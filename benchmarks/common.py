@@ -10,6 +10,8 @@ import time
 
 import jax
 
+from repro.runtime.peaks import V5E as _PUBLISHED
+
 
 def timeit(fn, *args, repeat: int = 3, warmup: int = 1, **kw):
     """Median wall-clock seconds of ``fn(*args)`` with block_until_ready."""
@@ -29,14 +31,14 @@ def row(name: str, us: float, **derived) -> str:
     return f"{name},{us:.1f},{d}"
 
 
-# TPU v5e roofline constants (the TARGET device; this container is CPU-only).
+# v5e projection constants: the peaks of runtime/peaks (the one table)
+# plus two MODELLED rates, which no published figure gives
 V5E = {
-    "peak_flops_bf16": 197e12,  # FLOP/s (MXU)
-    "peak_flops_f32": 49e12,    # MXU f32 ~ 1/4 bf16
-    "vpu_flops": 7e12,          # elementwise f32 ops/s (vector unit)
-    "hbm_bw": 819e9,            # B/s
-    "ici_bw": 50e9,             # B/s/link
-    "pcie_bw": 32e9,            # host->device B/s (transfer-stage projection)
+    **_PUBLISHED,
+    "peak_flops_f32": _PUBLISHED["peak_flops_bf16"] / 4,  # modelled: f32
+    # on the MXU as multi-pass bf16
+    "vpu_flops": _PUBLISHED["vpu_flops_f32"],  # modelled (runtime/peaks)
+    "pcie_bw": 32e9,     # modelled: host->device B/s (transfer projection)
 }
 
 

@@ -26,6 +26,8 @@ import json
 import sys
 import time
 
+from repro.runtime.compile_cache import use_compile_cache
+
 SUITES = ("table2", "fig1", "fig2", "pipeline", "soak", "serve", "roofline")
 
 
@@ -56,6 +58,7 @@ def main(argv=None):
     ap.add_argument("--json-pipeline", metavar="PATH", default=None,
                     help="write the batched-throughput trajectory record here")
     args = ap.parse_args(argv)
+    use_compile_cache()
     # validate by hand: a bare ``--only`` (empty list) used to silently
     # run NOTHING and exit 0, and an unknown name must die loudly
     if not args.only:

@@ -35,6 +35,7 @@ plan/executor pipeline:
 import argparse
 
 from repro.core.pipeline import BatchedExtractor
+from repro.runtime.compile_cache import use_compile_cache
 from repro.data.synthetic import stream_cases
 from repro.runtime.resilience import (
     FEATURE_NAMES,  # noqa: F401  (re-export kept for downstream scripts)
@@ -62,6 +63,7 @@ def main():
     ap.add_argument("--retries", type=int, default=2,
                     help="per-window collect retries (0 disables)")
     args = ap.parse_args()
+    use_compile_cache()
 
     def census(widx, s):
         print(f"window {widx}: {s['cases']} cases, "

@@ -19,9 +19,11 @@ import sys
 
 from repro.core.shape_features import ShapeFeatureExtractor
 from repro.data.synthetic import make_case
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     if len(sys.argv) == 3:  # real NIfTI inputs, as in the paper
         from repro.data.nifti import read_nifti
 

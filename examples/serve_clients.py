@@ -34,6 +34,7 @@ import time
 import numpy as np
 
 from repro.core.pipeline import BatchedExtractor
+from repro.runtime.compile_cache import use_compile_cache
 from repro.data.synthetic import mixed_traffic_stream, stream_cases
 
 
@@ -48,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--deadline-ms", type=float, default=5000.0)
     ap.add_argument("--queue-mb", type=float, default=64.0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     bx = BatchedExtractor(backend=args.backend, prep="hint",
                           schedule="static")

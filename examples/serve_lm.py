@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.registry import get_config, get_model, list_archs
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serve.serve_step import make_serve_step
 
 
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = get_model(cfg)

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs.base import RunConfig
 from repro.models.registry import get_config, get_model
+from repro.runtime.compile_cache import use_compile_cache
 from repro.train.trainer import Trainer
 
 # qwen3-family config scaled to ~100M params (d=512, L=8, untied embeddings)
@@ -50,6 +51,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny model + 5 steps (CI-speed sanity check)")
     args = ap.parse_args()
+    use_compile_cache()
 
     base = get_config(args.arch)
     if args.smoke:

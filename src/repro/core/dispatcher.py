@@ -4,9 +4,11 @@ The paper's C extension replaces one call site with a dispatcher that
 queries for a CUDA device at runtime and falls back to the original CPU
 implementation when none is found (or the driver fails).  Here:
 
-    'pallas'    -- compiled Pallas TPU kernels (requires a TPU backend)
+    'pallas'    -- compiled Pallas TPU kernels (requires a TPU backend:
+                   asking for it anywhere else is an error, never a
+                   silent fallback)
     'interpret' -- the same kernels executed in Pallas interpret mode
-                   (Python/CPU; used for validation in this container)
+                   (Python on the CPU; the test suite's kernel backend)
     'ref'       -- the pure-jnp reference path (the 'original CPU
                    implementation' role)
     'auto'      -- probe: TPU present -> 'pallas', else 'ref'
@@ -39,6 +41,12 @@ def resolve_backend(backend: Backend | None = None) -> str:
         backend = os.environ.get("REPRO_BACKEND", "auto")  # type: ignore
     if backend not in _VALID:
         raise ValueError(f"backend must be one of {_VALID}, got {backend!r}")
+    if backend == "pallas" and not has_tpu():
+        raise RuntimeError(
+            "backend='pallas' compiles Pallas kernels for a TPU, but JAX's "
+            f"default device is {jax.devices()[0].platform!r}; use "
+            "'interpret' or 'ref' on this host"
+        )
     if backend != "auto":
         return backend
     return "pallas" if has_tpu() else "ref"

@@ -193,6 +193,7 @@ from repro.core.plan import (  # noqa: F401  (re-exports)
 )
 from repro.core.tiled import TiledExtractor
 from repro.data.tiles import TiledCase
+from repro.kernels import marching_cubes as _mc
 
 
 class BatchedExtractor:
@@ -278,7 +279,8 @@ class BatchedExtractor:
 
         A ``TiledCase`` always does (constructing one is the opt-in).
         With ``tiled=True``, a materialized tuple whose staged frame
-        (mask + optional intensity, f32) would exceed the tile budget is
+        (mask + optional intensity, f32) plus, off ``ref``, its
+        marching-cubes temporaries would exceed the tile budget is
         converted too; loader callables stay in-core -- their shape is
         unknown until loaded (the serving layer's header peek handles
         byte estimation separately).
@@ -292,8 +294,11 @@ class BatchedExtractor:
         mask = np.asarray(case[1])
         if mask.ndim != 3:
             return False
-        staged = 4 * mask.size * (1 + int(self.executor._needs_intensity))
-        return staged > self.tiled_extractor.budget_bytes
+        ex = self.executor
+        need = 4 * mask.size * (1 + int(ex._needs_intensity))
+        if ex._shape_on and ex.backend != "ref":
+            need += _mc.work_bytes(mask.shape)
+        return need > self.tiled_extractor.budget_bytes
 
     def _as_tiled(self, case) -> TiledCase:
         if isinstance(case, TiledCase):

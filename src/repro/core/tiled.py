@@ -46,10 +46,11 @@ Data flow (one case)
    -> tuned diameter kernel.  First-order stats fold the mask-touched
    canonical chunks through ``kernels/firstorder.fold_packed_chunks``.
 
-Budget: ``REPRO_TILE_MEM_MB`` (default 256) bounds the STAGED bytes --
-two tiles' slabs (the submit/collect overlap holds at most two alive),
-mask + intensity.  Like ``plan.meta_bytes`` it deliberately counts
-staged arrays, not transient XLA temporaries.  GLCM needs neighbour
+Budget: ``REPRO_TILE_MEM_MB`` (default 256) bounds the device bytes --
+two tiles' staged slabs (the submit/collect overlap holds at most two
+alive), mask + intensity, plus one tile's marching-cubes temporaries on
+the brick-kernel backends (``kernels/marching_cubes.work_bytes``: the
+corner planes are several times the slab).  GLCM needs neighbour
 pairs across tile faces and is not offered tiled (``ValueError``).
 """
 from __future__ import annotations
@@ -62,6 +63,7 @@ import numpy as np
 
 from repro.core import plan as planlib
 from repro.kernels import firstorder as _fo
+from repro.kernels import marching_cubes as _mc
 from repro.kernels import ops
 
 DEFAULT_TILE_MEM_MB = 256.0
@@ -289,14 +291,20 @@ class TiledExtractor:
         n_slabs = -(-n_cells // cz)
         n_int = 1 + int(ex._needs_intensity)
         plane_bytes = Xb * Yb * 4 * n_int
-        # two tiles alive at once (submit k+1 / collect k overlap)
-        g = max(1, int((self.budget_bytes / 2 / plane_bytes - 1) // cz))
+        # per granule: two tiles' staged planes (submit k+1 / collect k
+        # overlap) and, on the brick kernel, one tile's MC temporaries
+        mc_granule = (_mc.work_bytes((Xb, Yb, cz + 1), mc_block)
+                      if mc_block is not None and ex._shape_on else 0)
+        g = max(1, (self.budget_bytes - 2 * plane_bytes)
+                // (2 * plane_bytes * cz + mc_granule))
         tile_bytes = plane_bytes * (g * cz + 1)
-        if 2 * tile_bytes > self.budget_bytes:
+        mc_work = g * mc_granule
+        if 2 * tile_bytes + mc_work > self.budget_bytes:
             warnings.warn(
                 f"tile budget {self.budget_bytes} B cannot hold two minimal "
-                f"{tile_bytes} B tiles of frame {bshape}; proceeding with "
-                "1-granule tiles over budget",
+                f"{tile_bytes} B tiles of frame {bshape} and {mc_work} B of "
+                "marching-cubes temporaries; proceeding with 1-granule "
+                "tiles over budget",
                 RuntimeWarning, stacklevel=2,
             )
         n_tiles = -(-n_slabs // g)
@@ -448,6 +456,7 @@ class TiledExtractor:
             "granule_cz": cz, "granules_per_tile": g,
             "tile_bytes": tile_bytes, "budget_bytes": self.budget_bytes,
             "staged_bytes_peak": 2 * tile_bytes,
+            "mc_work_bytes": mc_work,
             "n_vertices": n_total,
             "emitted_vertices": sum(len(r) for r in rank_list),
         }

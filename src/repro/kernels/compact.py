@@ -77,28 +77,83 @@ def compact_batch_ref(verts, keep, cap: int):
     return jax.vmap(lambda v, k: _compact_one_ref(v, k, cap))(verts, keep)
 
 
+_LANE = 128
+
+
+def padded_cap(cap: int, block: int) -> int:
+    """Lane width of the kernel's resident output row for ``cap`` slots.
+
+    ``cap`` rounded up to whole lanes, plus room for one scatter window
+    (``block + 128`` slots) opened at the last in-range aligned offset:
+    windows never run off the row, and the slots past ``cap`` they touch
+    are cut away afterwards (survivors beyond the cap are dropped).
+    """
+    return -(-cap // _LANE) * _LANE + block + _LANE
+
+
+# scoped-VMEM ceiling the kernel may ask for (a v5e core has 128 MiB)
+VMEM_LIMIT = 100 << 20
+
+
+def vmem_bytes(cap: int, block: int) -> int:
+    """Scoped VMEM the kernel needs: the double-buffered (3->8, cap_pad)
+    output row, the in/out blocks, the (block, block) prefix operator and
+    the (block + 128, block) one-hot window."""
+    f32 = 4
+    out = 2 * 8 * padded_cap(cap, block) * f32
+    ins = 2 * 2 * 8 * block * f32
+    work = (block * block + (block + _LANE) * block + 8 * (block + _LANE)) * f32
+    return out + ins + 2 * work
+
+
+def fits(cap: int, block: int) -> bool:
+    """Whether ``(cap, block)`` stays under :data:`VMEM_LIMIT` -- the bound
+    the autotune candidate list is filtered by."""
+    return vmem_bytes(cap, block) + (8 << 20) <= VMEM_LIMIT
+
+
 def _compact_kernel(kref, vref, vout, base, *, block: int, cap: int):
-    b, t = pl.program_id(0), pl.program_id(1)
-    del b  # the grid's case axis is routed entirely by the BlockSpecs
+    t = pl.program_id(1)
 
     @pl.when(t == 0)
-    def _():  # new case: reset the accumulator block + running offset
+    def _():  # new case: reset the accumulator row + running offset
         vout[...] = jnp.zeros_like(vout)
         base[0] = 0
 
-    ki = (kref[0, 0, :] > 0.0).astype(jnp.int32)  # (block,)
-    pos = jnp.cumsum(ki) - 1 + base[0]  # global output slot per survivor
-    cols = jax.lax.broadcasted_iota(jnp.int32, (block, cap), 1)
-    onehot = ((pos[:, None] == cols) & (ki[:, None] > 0)).astype(jnp.float32)
-    # scatter-by-matmul: each output column receives exactly one survivor
-    # (slots are unique), every other term is x * 0.0 -- exact in f32
-    vout[0] += jax.lax.dot_general(
-        vref[0],
-        onehot,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    base[0] = base[0] + jnp.sum(ki)
+    b0 = base[0]
+    k = kref[0]  # (1, block) 0/1 keep flags
+
+    @pl.when(b0 < cap)
+    def _():
+        # inclusive prefix sum as a 0/1 matmul with the upper-triangular
+        # ones operator (Mosaic has no cumsum): exact small integers
+        r = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        upper = (r <= c).astype(jnp.float32)
+        incl = jax.lax.dot_general(
+            k, upper, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )  # (1, block)
+        # this block's survivors land in slots [b0, b0 + block): open a
+        # lane-aligned window of block + 128 slots over them
+        w0 = pl.multiple_of((b0 // _LANE) * _LANE, _LANE)
+        local = incl - 1.0 + (b0 - w0).astype(jnp.float32)  # slot - w0
+        width = block + _LANE
+        rows = jax.lax.broadcasted_iota(jnp.int32, (width, block), 0)
+        onehot_t = ((rows.astype(jnp.float32) == local) & (k > 0.0)).astype(
+            jnp.float32)  # (width, block): window slot <- survivor
+        # scatter-by-matmul: each window slot receives at most one
+        # survivor (slots are unique), every other term is x * 0.0 -- exact
+        # in f32 at HIGHEST precision (the MXU default rounds x to bf16)
+        win = jax.lax.dot_general(
+            vref[0], onehot_t, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )  # (3, width)
+        vout[0, :, pl.ds(w0, width)] += win
+
+    base[0] = b0 + jnp.sum(k).astype(jnp.int32)
 
 
 @functools.partial(
@@ -112,7 +167,9 @@ def compact_batch_pallas(
 
     ``verts``: (B, M, 3), ``keep``: (B, M) -> ``(out, mask, n)``.  The grid
     is ``(B, M/block)``; case ``b``'s blocks run sequentially, carrying the
-    survivor offset in SMEM, and revisit one (3, cap) output accumulator.
+    survivor offset in SMEM, and revisit one resident (3, cap) output row.
+    Each block scatters through a one-hot window of ``block + 128`` slots
+    only, so the working set is bounded by ``block``, not by ``cap``.
     """
     verts = jnp.asarray(verts, jnp.float32)
     kf = jnp.asarray(keep).astype(jnp.float32)
@@ -121,6 +178,7 @@ def compact_batch_pallas(
     pad = nb * block - M
     v = jnp.pad(verts, ((0, 0), (0, pad), (0, 0))).transpose(0, 2, 1)
     km = jnp.pad(kf, ((0, 0), (0, pad)))[:, None, :]  # (B, 1, nb*block)
+    cap_pad = padded_cap(cap, block)
 
     out = pl.pallas_call(
         functools.partial(_compact_kernel, block=block, cap=cap),
@@ -129,9 +187,11 @@ def compact_batch_pallas(
             pl.BlockSpec((1, 1, block), lambda b, t: (b, 0, t)),
             pl.BlockSpec((1, 3, block), lambda b, t: (b, 0, t)),
         ],
-        out_specs=pl.BlockSpec((1, 3, cap), lambda b, t: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 3, cap), jnp.float32),
+        out_specs=pl.BlockSpec((1, 3, cap_pad), lambda b, t: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 3, cap_pad), jnp.float32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(
+            VMEM_LIMIT, max(32 << 20, vmem_bytes(cap, block) + (8 << 20)))),
         interpret=interpret,
     )(km, v)
 
@@ -140,4 +200,4 @@ def compact_batch_pallas(
         jax.lax.broadcasted_iota(jnp.int32, (B, cap), 1)
         < jnp.minimum(n, cap)[:, None]
     )
-    return out.transpose(0, 2, 1), mask, n
+    return out[:, :, :cap].transpose(0, 2, 1), mask, n

@@ -17,21 +17,26 @@ in-kernel accumulator -- see variants).
 
 Optimization variants (the TPU analogue of the paper's Fig. 1 study):
     'naive'  : one pass per combo (4 separate kernel launches), full grid.
-    'fused'  : all 4 combos in one pass, full grid.          [mem-access opt]
+    'fused'  : all 4 combos in one pass, full grid; one partial-maximum
+               tile per row block, revisited across the j sweep and
+               reduced outside.                                [mem-access opt]
     'tri'    : fused + predicated skip of lower-triangle blocks (j < i).
                DMA still runs; compute is skipped.            [load balance]
     'seqacc' : fused + triangular + single in-kernel accumulator block that
                is revisited across the sequential TPU grid -- the analogue of
                the paper's per-thread local accumulators (vs. the partial-
                output blocks, which are its 'block-based reduction').
-    'tri_prefetch': fused + a 1-D grid over only the nb*(nb+1)/2 upper-
-               triangle block pairs, with the (i, j) schedule delivered via
-               scalar prefetch so skipped blocks cost neither DMA nor compute
-               -- the TPU-native version of CUDA early-exit load balancing.
+    'tri_prefetch': fused + a grid over only the nb*(nb+1)/2 upper-
+               triangle block pairs (:func:`tri_grid`), with (i, j) derived
+               from the grid point in the index maps (:func:`tri_index`) so
+               skipped blocks cost neither DMA nor compute -- the TPU-native
+               version of CUDA early-exit load balancing.  (The name is
+               kept from an earlier scalar-prefetched schedule table, which
+               grew as nb^2 and overflowed SMEM at nb >= 512.)
     'nomask' : tri_prefetch without the mask streams: invalid slots are
                pre-filled with the first valid vertex, so the mask DMA and
                the per-pair select disappear.
-    'gram'   : tri_prefetch schedule, but the per-tile pair distances are
+    'gram'   : the triangular schedule, but the per-tile pair distances are
                computed on the MXU via the (augmented) Gram identity
                    |r_i - c_j|^2 = |r_i|^2 + |c_j|^2 - 2 <r_i, c_j>
                realised per axis as [r^2, 1, -2r] @ [1, c^2, c]^T -- the
@@ -58,13 +63,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 NEG = np.float32(-1e30)
 VARIANTS = ("naive", "fused", "tri", "seqacc", "tri_prefetch", "nomask", "gram")
-
-# variants scheduled on the triangular scalar-prefetch 1-D grid
-_PREFETCH_VARIANTS = ("tri_prefetch", "gram")
 
 
 def _pairwise_combos(rows, cols, rmask, cmask, combos):
@@ -99,7 +100,8 @@ def _pairwise_combos_gram(rows, cols, rmask, cmask, combos):
     three axis products are batched into a single call and kept separate,
     so all 4 combos (3D/xy/xz/yz) are served from the same 3 MXU products;
     the VPU only does the per-combo adds + select + max, not the
-    subtract-square sweep.
+    subtract-square sweep.  ``Precision.HIGHEST`` keeps the products in
+    f32 on the MXU (its default would round the operands to bf16).
     """
     ones = jnp.ones_like(rows)
     lhs = jnp.stack([rows * rows, ones, -2.0 * rows], axis=-1)  # (3, B, 3)
@@ -108,6 +110,7 @@ def _pairwise_combos_gram(rows, cols, rmask, cmask, combos):
         lhs,
         rhs,
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )  # (3, B, B): per-axis squared differences
     valid = (rmask[0][:, None] > 0.0) & (cmask[0][None, :] > 0.0)
@@ -119,19 +122,36 @@ def _pairwise_combos_gram(rows, cols, rmask, cmask, combos):
     return jnp.stack(outs)
 
 
+def _row_tile(part, nc):
+    """Place the (nc,) partial maxima in lanes 0..nc-1 of an (8, 128) tile.
+
+    The per-row-block partial output of 'fused'/'tri' is one native
+    (8, 128) f32 tile per row block; the other lanes hold NEG.
+    """
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    tile = jnp.full((8, 128), NEG, jnp.float32)
+    for c in range(nc):
+        tile = jnp.where(lane == c, part[c], tile)
+    return tile
+
+
 def _kernel_partial(vr, mr, vc, mc, out, *, combos, triangular):
+    """One running partial per row block i, revisited across the j sweep."""
     i, j = pl.program_id(0), pl.program_id(1)
+    nc = len(combos)
+
+    @pl.when(j == 0)
+    def _():
+        out[0] = jnp.full((8, 128), NEG, jnp.float32)
+
+    def update():
+        part = _pairwise_combos(vr[:], vc[:], mr[:], mc[:], combos)
+        out[0] = jnp.maximum(out[0], _row_tile(part, nc))
 
     if triangular:
-        @pl.when(j >= i)
-        def _():
-            out[0, 0, :] = _pairwise_combos(vr[:], vc[:], mr[:], mc[:], combos)
-
-        @pl.when(j < i)
-        def _():
-            out[0, 0, :] = jnp.full((len(combos),), NEG)
+        pl.when(j >= i)(update)
     else:
-        out[0, 0, :] = _pairwise_combos(vr[:], vc[:], mr[:], mc[:], combos)
+        update()
 
 
 def _kernel_seqacc(vr, mr, vc, mc, out, *, combos):
@@ -147,10 +167,33 @@ def _kernel_seqacc(vr, mr, vc, mc, out, *, combos):
         out[0, :] = jnp.maximum(out[0, :], part)
 
 
-def _kernel_tri_prefetch(ij_ref, vr, mr, vc, mc, out, *, combos, tile_fn):
-    t = pl.program_id(0)
+def tri_grid(nb: int) -> tuple[int, int]:
+    """Grid of the folded upper-triangle schedule over ``nb`` blocks.
 
-    @pl.when(t == 0)
+    Row block ``a`` (of the first ``ceil(nb/2)``) is paired with row block
+    ``nb-1-a``: together they hold ``nb + 1`` upper-triangle tiles, so the
+    ``nb*(nb+1)/2`` tiles fill a ``(ceil(nb/2), nb+1)`` rectangle.  For odd
+    ``nb`` the middle row is paired with itself and its tiles run twice,
+    which a max-reduction absorbs exactly.
+    """
+    return (nb + 1) // 2, nb + 1
+
+
+def tri_index(a, c, nb: int):
+    """(i, j) tile of the folded schedule at grid point ``(a, c)``.
+
+    Integer scalar arithmetic, evaluated in the BlockSpec index maps: the
+    schedule needs no prefetched table, so its SMEM footprint does not
+    grow with ``nb``.
+    """
+    first = c < nb - a
+    i = jnp.where(first, a, nb - 1 - a)
+    j = jnp.where(first, a + c, c - 1)
+    return i, j
+
+
+def _kernel_tri(vr, mr, vc, mc, out, *, combos, tile_fn):
+    @pl.when(jnp.logical_and(pl.program_id(0) == 0, pl.program_id(1) == 0))
     def _():
         out[0, :] = jnp.full((len(combos),), NEG)
 
@@ -171,14 +214,12 @@ def _combos_nomask(rows, cols, combos):
     return jnp.stack(outs)
 
 
-def _kernel_nomask(ij_ref, vr, vc, out, *, combos):
-    """Beyond-paper variant (§Perf/3): triangular scalar-prefetch schedule
-    with NO mask streams.  Invalid slots were pre-filled with the first
-    valid vertex (a duplicated point can never raise the max), so the mask
-    DMA (2 of 8 input streams) and the per-pair select disappear."""
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
+def _kernel_nomask(vr, vc, out, *, combos):
+    """Beyond-paper variant (§Perf/3): the folded triangular schedule with
+    NO mask streams.  Invalid slots were pre-filled with the first valid
+    vertex (a duplicated point can never raise the max), so the mask DMA
+    (2 of 8 input streams) and the per-pair select disappear."""
+    @pl.when(jnp.logical_and(pl.program_id(0) == 0, pl.program_id(1) == 0))
     def _():
         out[0, :] = jnp.full((len(combos),), NEG)
 
@@ -229,29 +270,44 @@ def max_diameters_sq_pallas(
 
     v, m, nb = _pad_inputs(verts, mask, block)
     nc = len(combos)
+    acc_spec = pl.BlockSpec((1, nc), lambda *_: (0, 0))
+    acc_shape = jax.ShapeDtypeStruct((1, nc), jnp.float32)
 
-    if variant == "nomask":
-        # pre-fill invalid slots with the first valid vertex; padding from
-        # _pad_inputs is masked-out, so it is filled too
-        first = jnp.argmax(m[0] > 0.0)
-        v = jnp.where(m > 0.0, v, v[:, first][:, None])
-        ii, jj = np.triu_indices(nb)
-        ij = jnp.asarray(np.stack([ii, jj]).astype(np.int32))
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(len(ii),),
-            in_specs=[
-                pl.BlockSpec((3, block), lambda t, ij: (0, ij[0, t])),
-                pl.BlockSpec((3, block), lambda t, ij: (0, ij[1, t])),
-            ],
-            out_specs=pl.BlockSpec((1, nc), lambda t, ij: (0, 0)),
-        )
+    if variant in ("tri_prefetch", "nomask", "gram"):
+        # folded triangular schedule: only upper-triangle tiles are visited
+        def rows(a, c):
+            return (0, tri_index(a, c, nb)[0])
+
+        def cols(a, c):
+            return (0, tri_index(a, c, nb)[1])
+
+        if variant == "nomask":
+            # pre-fill invalid slots with the first valid vertex; padding
+            # from _pad_inputs is masked-out, so it is filled too
+            first = jnp.argmax(m[0] > 0.0)
+            v = jnp.where(m > 0.0, v, v[:, first][:, None])
+            kernel = functools.partial(_kernel_nomask, combos=combos)
+            in_specs = [pl.BlockSpec((3, block), rows),
+                        pl.BlockSpec((3, block), cols)]
+            args = (v, v)
+        else:
+            tile_fn = (_pairwise_combos_gram if variant == "gram"
+                       else _pairwise_combos)
+            kernel = functools.partial(_kernel_tri, combos=combos,
+                                       tile_fn=tile_fn)
+            in_specs = [pl.BlockSpec((3, block), rows),
+                        pl.BlockSpec((1, block), rows),
+                        pl.BlockSpec((3, block), cols),
+                        pl.BlockSpec((1, block), cols)]
+            args = (v, m, v, m)
         out = pl.pallas_call(
-            functools.partial(_kernel_nomask, combos=combos),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((1, nc), jnp.float32),
+            kernel,
+            grid=tri_grid(nb),
+            in_specs=in_specs,
+            out_specs=acc_spec,
+            out_shape=acc_shape,
             interpret=interpret,
-        )(ij, v, v)
+        )(*args)
         return jnp.maximum(out[0], 0.0)
 
     row_spec = pl.BlockSpec((3, block), lambda i, j: (0, i))
@@ -266,48 +322,20 @@ def max_diameters_sq_pallas(
             ),
             grid=(nb, nb),
             in_specs=[row_spec, rmask_spec, col_spec, cmask_spec],
-            out_specs=pl.BlockSpec((1, 1, nc), lambda i, j: (i, j, 0)),
-            out_shape=jax.ShapeDtypeStruct((nb, nb, nc), jnp.float32),
+            out_specs=pl.BlockSpec((1, 8, 128), lambda i, j: (i, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((nb, 8, 128), jnp.float32),
             interpret=interpret,
         )(v, m, v, m)
-        best = jnp.max(out, axis=(0, 1))
-    elif variant == "seqacc":
+        best = jnp.max(out[:, 0, :nc], axis=0)
+    else:  # seqacc
         out = pl.pallas_call(
             functools.partial(_kernel_seqacc, combos=combos),
             grid=(nb, nb),
             in_specs=[row_spec, rmask_spec, col_spec, cmask_spec],
-            out_specs=pl.BlockSpec((1, nc), lambda i, j: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((1, nc), jnp.float32),
+            out_specs=acc_spec,
+            out_shape=acc_shape,
             interpret=interpret,
         )(v, m, v, m)
-        best = out[0]
-    else:  # tri_prefetch / gram: triangular scalar-prefetch schedule
-        ii, jj = np.triu_indices(nb)
-        nsteps = len(ii)
-        ij = jnp.asarray(np.stack([ii, jj]).astype(np.int32))  # (2, T)
-        tile_fn = (
-            _pairwise_combos_gram if variant == "gram" else _pairwise_combos
-        )
-
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(nsteps,),
-            in_specs=[
-                pl.BlockSpec((3, block), lambda t, ij: (0, ij[0, t])),
-                pl.BlockSpec((1, block), lambda t, ij: (0, ij[0, t])),
-                pl.BlockSpec((3, block), lambda t, ij: (0, ij[1, t])),
-                pl.BlockSpec((1, block), lambda t, ij: (0, ij[1, t])),
-            ],
-            out_specs=pl.BlockSpec((1, nc), lambda t, ij: (0, 0)),
-        )
-        out = pl.pallas_call(
-            functools.partial(
-                _kernel_tri_prefetch, combos=combos, tile_fn=tile_fn
-            ),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((1, nc), jnp.float32),
-            interpret=interpret,
-        )(ij, v, m, v, m)
         best = out[0]
     return jnp.maximum(best, 0.0)
 

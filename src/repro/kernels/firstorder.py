@@ -74,7 +74,9 @@ def _chunk_stats(x, m, q, n_bins: int):
     slices, so per-chunk partials lower to the same reductions and match
     bitwise across backends.
     """
-    cols = jax.lax.broadcasted_iota(jnp.float32, (CANON_CHUNK, n_bins), 1)
+    # integer iota, then cast: Mosaic has no f32 iota
+    cols = jax.lax.broadcasted_iota(jnp.int32, (CANON_CHUNK, n_bins), 1).astype(
+        jnp.float32)
     onehot = ((q[:, None] == cols) & (m[:, None] > 0)).astype(jnp.float32)
     return jnp.concatenate([
         jnp.stack([jnp.sum(m), jnp.sum(x), jnp.sum(x * x)]),
@@ -237,7 +239,7 @@ def _fo_kernel(xref, mref, qref, out, *, block: int, n_bins: int):
         sl = slice(j * CANON_CHUNK, (j + 1) * CANON_CHUNK)
         vec = _chunk_stats(xref[0, 0, sl], mref[0, 0, sl], qref[0, 0, sl],
                            n_bins)
-        out[...] += vec[None, :]
+        out[0] += vec[None, :]
 
 
 @functools.partial(jax.jit,
@@ -260,11 +262,12 @@ def firstorder_packed_batch_pallas(images, masks, *, n_bins: int = N_BINS,
         functools.partial(_fo_kernel, block=block, n_bins=n_bins),
         grid=grid,
         in_specs=[spec, spec, spec],
-        out_specs=pl.BlockSpec((1, w), lambda b, t: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, w), jnp.float32),
+        # (B, 1, w): a (1, w) block spans the array's last two dims
+        out_specs=pl.BlockSpec((1, 1, w), lambda b, t: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, w), jnp.float32),
         interpret=interpret,
     )(x[:, None, :], m[:, None, :], q[:, None, :])
-    return _pack(stats, lo, hi, width)
+    return _pack(stats[:, 0], lo, hi, width)
 
 
 def firstorder_features_batch_pallas(images, masks, *, n_bins: int = N_BINS,

@@ -42,21 +42,34 @@ FEATURES = ("Contrast", "Correlation", "Idm", "JointEnergy")
 N_FEATURES = len(FEATURES)
 
 
+def _shift(x, axis):
+    """``x`` moved one voxel down ``axis``: ``y[i] = x[i + 1]``, zero-filled."""
+    lo = tuple(slice(1, None) if a == axis else slice(None)
+               for a in range(x.ndim))
+    pad = tuple((0, 1) if a == axis else (0, 0) for a in range(x.ndim))
+    return jnp.pad(x[lo], pad)
+
+
 def pair_arrays(q, m):
     """Flatten one case's co-occurrence pairs: ``(q1, q2, valid)``.
 
     ``q`` is the f32 bin-id volume, ``m`` the f32 mask; each offset in
-    :data:`OFFSETS` contributes the overlapping slab of (voxel, neighbour)
-    pairs.  The concatenated length is static given the volume shape, so
-    the executor's shape buckets key the pair length too.
+    :data:`OFFSETS` contributes one (voxel, neighbour) pair per voxel, the
+    neighbour read from a zero-filled shifted copy of the volume, so the
+    pairs that leave the volume have ``valid == 0`` and count nothing.
+    Every offset keeps the volume's own shape: the flattening is a plain
+    reshape of a full bucket-shaped volume (the sliced ``(n-1)``-long axes
+    an exact-overlap formulation needs cost the TPU compiler minutes of
+    relayout at 128^3).  The concatenated length is ``3 * q.size``, static
+    given the volume shape, so the executor's shape buckets key the pair
+    length too.
     """
     q1s, q2s, vs = [], [], []
     for off in OFFSETS:
-        a = tuple(slice(None, -o) if o else slice(None) for o in off)
-        b = tuple(slice(o, None) for o in off)
-        q1s.append(q[a].reshape(-1))
-        q2s.append(q[b].reshape(-1))
-        vs.append((m[a] * m[b]).reshape(-1))
+        axis = off.index(1)
+        q1s.append(q.reshape(-1))
+        q2s.append(_shift(q, axis).reshape(-1))
+        vs.append((m * _shift(m, axis)).reshape(-1))
     return jnp.concatenate(q1s), jnp.concatenate(q2s), jnp.concatenate(vs)
 
 
@@ -153,7 +166,9 @@ def _glcm_kernel(q1ref, q2ref, vref, out, *, block: int, n_bins: int):
     q1 = q1ref[0, 0, :]
     q2 = q2ref[0, 0, :]
     v = vref[0, 0, :]
-    cols = jax.lax.broadcasted_iota(jnp.float32, (block, n_bins), 1)
+    # integer iota, then cast: Mosaic has no f32 iota
+    cols = jax.lax.broadcasted_iota(jnp.int32, (block, n_bins), 1).astype(
+        jnp.float32)
     # invalid/padded pairs are zeroed on the LEFT factor only: one dead
     # row in oh1 kills the whole pair
     oh1 = ((q1[:, None] == cols) & (v[:, None] > 0)).astype(jnp.float32)

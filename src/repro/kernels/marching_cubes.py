@@ -4,16 +4,19 @@ PyRadiomics-cuda's first kernel walks every voxel with one CUDA thread,
 emitting triangles and atomically accumulating mesh volume and surface area.
 The TPU adaptation:
 
-* the volume is restacked host-side into **overlapping (BX+1, BY+1, CZ+1)
-  bricks** (the +1 halo shares one plane with the neighbour -- the analogue
-  of staging tiles in CUDA shared memory).  Memory overhead is
-  (1+1/BX)(1+1/BY)(1+1/CZ) ~ 1.2-1.4x, streamed HBM->VMEM by the Pallas
-  pipeline;
+* the volume is laid out by XLA as **brick-major corner planes**: for each
+  (BX, BY, BZ) brick, an (8, BX*BY*BZ) block whose row ``c`` is corner
+  ``c`` of every cell (the analogue of staging tiles in CUDA shared
+  memory).  The kernel then works on lane-dense (rows, cells) tiles with
+  no gather, relayout or unaligned slice -- what Mosaic can lower -- at
+  the price of 8 floats of HBM traffic per cell;
 * the per-voxel triangle-table *gather* (which TPUs dislike) becomes a
-  **one-hot matmul on the MXU**: ``onehot(cube_index, 256) @ TRI_TABLE`` --
-  data-dependent lookup expressed as dense systolic compute;
+  **one-hot matmul on the MXU**: ``TABLE @ onehot(cube_index, 256)`` --
+  data-dependent lookup expressed as dense systolic compute (0/1 and small
+  integers, exact in bf16);
 * CUDA atomic accumulation becomes per-brick partial sums written to their
-  own output cells and reduced outside (deterministic, Megacore-safe);
+  own output cells and reduced outside in a fixed pairwise order
+  (deterministic, Megacore-safe);
 * triangle *vertices* are not appended to a global list at all: the
   deduplicated vertex field is a dense per-grid-edge structure computed in a
   single fused elementwise XLA pass (see ``kernels/ref.vertex_fields``) --
@@ -36,129 +39,150 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import mc_tables as mct
 
-_NSLOTS = mct.MAX_TRIS * 3  # 15 table slots per case
+_GROUP = 8  # sublane rows per triangle-vertex group (MAX_TRIS <= 8)
 
 
-def _brick_cells(s, iso, x0, y0, z0, spacing, origin):
-    """Per-cell edge-vertex positions + cube index for one brick.
+def _grouped_table() -> np.ndarray:
+    """(3 * 8, 256) transposed triangle table, grouped by triangle vertex.
 
-    s: (BX+1, BY+1, CZ+1) corner values.  Returns (E, idx) with
-    E: (12, BX*BY*CZ, 3) physical positions, idx: (BX*BY*CZ,) int32.
+    Row ``g * 8 + t`` holds the edge id of vertex ``g`` (a, b, c) of
+    triangle ``t`` for every cube case, -1 where the case has no such
+    triangle.  ``table @ onehot(case)`` then yields the a, b and c edge ids
+    as three sublane-aligned (8, cells) row groups.
     """
-    bx, by, cz = s.shape[0] - 1, s.shape[1] - 1, s.shape[2] - 1
-    inside = (s > iso).astype(jnp.int32)
+    tri = np.asarray(mct.TRI_TABLE).reshape(256, mct.MAX_TRIS, 3)
+    out = np.full((3 * _GROUP, 256), -1.0, np.float32)
+    for g in range(3):
+        out[g * _GROUP:g * _GROUP + mct.MAX_TRIS] = tri[:, :, g].T
+    return out
 
-    idx = jnp.zeros((bx, by, cz), jnp.int32)
-    for c, (dx, dy, dz) in enumerate(np.asarray(mct.CORNERS)):
-        idx = idx + (inside[dx : dx + bx, dy : dy + by, dz : dz + cz] << c)
+
+_TABLE = _grouped_table()
+
+
+def _corner_index(off) -> int:
+    return int(np.flatnonzero((np.asarray(mct.CORNERS) == off).all(axis=1))[0])
+
+
+# per edge: (axis, anchor offset, lower corner, upper corner) -- the edge
+# runs from its anchor grid point one cell along ``axis``
+_EDGES = tuple(
+    (
+        int(ax),
+        tuple(int(o) for o in off),
+        _corner_index(off),
+        _corner_index(np.asarray(off) + np.eye(3, dtype=np.int32)[ax]),
+    )
+    for ax, off in zip(mct.EDGE_CELL_AXIS, mct.EDGE_CELL_OFFSET)
+)
+
+
+def _cell_coords(block) -> np.ndarray:
+    """(8, cells) brick-local cell coordinates: rows 0-2 = (ix, iy, iz).
+
+    Cells are numbered x-major (``ix * by * bz + iy * bz + iz``), the
+    order :func:`_corner_bricks` lays them out in.
+    """
+    bx, by, cz = block
+    g = np.indices((bx, by, cz), dtype=np.float32).reshape(3, -1)
+    return np.concatenate([g, np.zeros((5, g.shape[1]), np.float32)])
+
+
+def _mc_kernel(scal, table_ref, cell_ref, corner_ref, out, *, chunk,
+               block, z_scal=False):
+    """One brick: fused table lookup (MXU one-hot matmul) + vol/area sums.
+
+    ``corner_ref`` holds the brick's cells lane-major: row ``c`` is corner
+    ``c``'s value for every cell.  Each chunk of cells is processed as
+    (rows, chunk) tiles with no gather, relayout or 3-D array: cube index
+    and edge interpolation are row arithmetic, the triangle table lookup is
+    one (24, 256) x (256, chunk) 0/1 matmul, and the edge-vertex select is
+    a 12-way masked merge on the VPU.
+
+    With ``z_scal`` (the tiled entry) ``scal`` carries an 8th element:
+    the window's global z offset in cells, added to the brick-local z
+    base.  Both are integer-valued f32 < 2^24, so the add is exact and
+    the brick computes with the SAME coordinates as the in-core grid.
+    The brick's (signed volume, area) pair goes to lanes 0 and 1 of row
+    ``k`` of its (i, j) column's output block.
+    """
+    iso = scal[0]
+    spacing = (scal[1], scal[2], scal[3])
+    origin = (scal[4], scal[5], scal[6])
+    bx, by, cz = block
+    cells = bx * by * cz
+
+    i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    base0 = (
+        (i * bx).astype(jnp.float32),
+        (j * by).astype(jnp.float32),
+        (k * cz).astype(jnp.float32),
+    )
+    if z_scal:
+        base0 = (base0[0], base0[1], base0[2] + scal[7])
+    table = table_ref[...].astype(jnp.bfloat16)  # exact: ids in [-1, 11]
 
     def interp(v0, v1):
         den = v1 - v0
         den = jnp.where(jnp.abs(den) < 1e-30, 1.0, den)
         return jnp.clip((iso - v0) / den, 0.0, 1.0)
 
-    tx = interp(s[:-1, :, :], s[1:, :, :])  # (BX, BY+1, CZ+1)
-    ty = interp(s[:, :-1, :], s[:, 1:, :])  # (BX+1, BY, CZ+1)
-    tz = interp(s[:, :, :-1], s[:, :, 1:])  # (BX+1, BY+1, CZ)
-
-    spx, spy, spz = spacing
-    ox, oy, oz = origin
-
-    def coords(shape, fx, fy, fz):
-        ii = jax.lax.broadcasted_iota(jnp.float32, shape, 0)
-        jj = jax.lax.broadcasted_iota(jnp.float32, shape, 1)
-        kk = jax.lax.broadcasted_iota(jnp.float32, shape, 2)
-        px = (x0 + ii + fx) * spx + ox
-        py = (y0 + jj + fy) * spy + oy
-        pz = (z0 + kk + fz) * spz + oz
-        return jnp.stack([px, py, pz], axis=-1)
-
-    # Vertex positions on the three canonical edge families.
-    px = coords(tx.shape, tx, 0.0, 0.0)  # x-directed edges
-    py = coords(ty.shape, 0.0, ty, 0.0)
-    pz = coords(tz.shape, 0.0, 0.0, tz)
-
-    e = [None] * 12
-    e[0] = px[:, :-1, :-1]
-    e[2] = px[:, 1:, :-1]
-    e[4] = px[:, :-1, 1:]
-    e[6] = px[:, 1:, 1:]
-    e[3] = py[:-1, :, :-1]
-    e[1] = py[1:, :, :-1]
-    e[7] = py[:-1, :, 1:]
-    e[5] = py[1:, :, 1:]
-    e[8] = pz[:-1, :-1, :]
-    e[9] = pz[1:, :-1, :]
-    e[10] = pz[1:, 1:, :]
-    e[11] = pz[:-1, 1:, :]
-    E = jnp.stack([x.reshape(-1, 3) for x in e])  # (12, cells, 3)
-    return E, idx.reshape(-1)
-
-
-def _mc_kernel(scal, table_ref, brick, vol_out, area_out, *, chunk,
-               z_scal=False):
-    """One brick: fused table lookup (MXU one-hot matmul) + vol/area sums.
-
-    With ``z_scal`` (the tiled entry) ``scal`` carries an 8th element:
-    the window's global z offset in cells, added to the brick-local z
-    base.  Both are integer-valued f32 < 2^24, so the add is exact and
-    the brick computes with the SAME coordinates as the in-core grid.
-    """
-    iso = scal[0]
-    spacing = (scal[1], scal[2], scal[3])
-    origin = (scal[4], scal[5], scal[6])
-    bx1 = brick.shape[3]
-    by1 = brick.shape[4]
-    cz1 = brick.shape[5]
-    bx, by, cz = bx1 - 1, by1 - 1, cz1 - 1
-
-    px_id = pl.program_id(0)
-    py_id = pl.program_id(1)
-    pz_id = pl.program_id(2)
-    x0 = (px_id * bx).astype(jnp.float32)
-    y0 = (py_id * by).astype(jnp.float32)
-    z0 = (pz_id * cz).astype(jnp.float32)
-    if z_scal:
-        z0 = z0 + scal[7]
-
-    s = brick[0, 0, 0]
-    E, idx = _brick_cells(s, iso, x0, y0, z0, spacing, origin)
-    cells = bx * by * cz
-
-    table = table_ref[:]  # (256, 15) f32 triangle table, resident in VMEM
-
     def chunk_body(c0, acc):
         sv, sa = acc
-        idx_c = jax.lax.dynamic_slice_in_dim(idx, c0 * chunk, chunk)
-        E_c = jax.lax.dynamic_slice_in_dim(E, c0 * chunk, chunk, axis=1)
-        # --- one-hot matmul gather (MXU) ---
-        oh = (idx_c[:, None] == jax.lax.broadcasted_iota(jnp.int32, (chunk, 256), 1)).astype(jnp.float32)
+        start = pl.multiple_of(c0 * chunk, chunk)
+        cv = corner_ref[0, 0, 0, :, pl.ds(start, chunk)]  # (8, chunk)
+        cc = cell_ref[:, pl.ds(start, chunk)]  # (8, chunk)
+        corner = [cv[c:c + 1] for c in range(8)]
+        idx = jnp.zeros((1, chunk), jnp.int32)
+        for c in range(8):
+            idx = idx + ((corner[c] > iso).astype(jnp.int32) << c)
+        # --- one-hot matmul gather (MXU): 0/1 x small ints, exact ---
+        cases = jax.lax.broadcasted_iota(jnp.int32, (256, chunk), 0)
+        onehot = (cases == idx).astype(jnp.bfloat16)
         ids = jax.lax.dot_general(
-            oh, table, (((1,), (0,)), ((), ())),
+            table, onehot, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (chunk, 15) float edge ids, exact small ints
-        sel = (
-            ids[:, :, None]
-            == jax.lax.broadcasted_iota(jnp.float32, (chunk, _NSLOTS, 12), 2)
-        ).astype(jnp.float32)  # (chunk, 15, 12)
-        Ec = jnp.transpose(E_c, (1, 0, 2))  # (chunk, 12, 3)
-        verts = jax.lax.dot_general(
-            sel, Ec, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # (chunk, 15, 3)
-        tri = verts.reshape(chunk, mct.MAX_TRIS, 3, 3)
-        valid = (ids.reshape(chunk, mct.MAX_TRIS, 3)[:, :, 0] >= 0.0).astype(jnp.float32)
-        a, b, c = tri[:, :, 0, :], tri[:, :, 1, :], tri[:, :, 2, :]
-        ab, ac = b - a, c - a
-        cr = jnp.cross(ab, ac)
-        area = 0.5 * jnp.sqrt(jnp.sum(cr * cr, axis=-1) + 1e-30) * valid
-        svol = jnp.sum(a * jnp.cross(b, c), axis=-1) / 6.0 * valid
-        return sv + jnp.sum(svol), sa + jnp.sum(area)
+        )  # (24, chunk) edge ids, -1 = no triangle
+        # cell origin in grid units (exact small integers)
+        base = [base0[d] + cc[d:d + 1] for d in range(3)]
+        vx = jnp.zeros(ids.shape, jnp.float32)
+        vy = jnp.zeros(ids.shape, jnp.float32)
+        vz = jnp.zeros(ids.shape, jnp.float32)
+        for e, (ax, off, lo, hi) in enumerate(_EDGES):
+            t = interp(corner[lo], corner[hi])
+            pos = []
+            for d in range(3):
+                g = base[d] + float(off[d])
+                if d == ax:
+                    g = g + t
+                pos.append(g * spacing[d] + origin[d])
+            hit = ids == float(e)
+            vx = jnp.where(hit, pos[0], vx)
+            vy = jnp.where(hit, pos[1], vy)
+            vz = jnp.where(hit, pos[2], vz)
+        a = (vx[0:8], vy[0:8], vz[0:8])
+        b = (vx[8:16], vy[8:16], vz[8:16])
+        c = (vx[16:24], vy[16:24], vz[16:24])
+        valid = (ids[0:8] >= 0.0).astype(jnp.float32)
+        ab = [b[d] - a[d] for d in range(3)]
+        ac = [c[d] - a[d] for d in range(3)]
+        cr = (ab[1] * ac[2] - ab[2] * ac[1],
+              ab[2] * ac[0] - ab[0] * ac[2],
+              ab[0] * ac[1] - ab[1] * ac[0])
+        bc = (b[1] * c[2] - b[2] * c[1],
+              b[2] * c[0] - b[0] * c[2],
+              b[0] * c[1] - b[1] * c[0])
+        area = 0.5 * jnp.sqrt(cr[0] * cr[0] + cr[1] * cr[1] + cr[2] * cr[2]
+                              + 1e-30) * valid
+        svol = (a[0] * bc[0] + a[1] * bc[1] + a[2] * bc[2]) / 6.0 * valid
+        return sv + svol, sa + area
 
-    nchunks = cells // chunk
-    sv, sa = jax.lax.fori_loop(0, nchunks, chunk_body, (jnp.float32(0), jnp.float32(0)))
-    vol_out[0, 0, 0] = sv
-    area_out[0, 0, 0] = sa
+    zero = jnp.zeros((_GROUP, chunk), jnp.float32)
+    sv, sa = jax.lax.fori_loop(0, cells // chunk, chunk_body, (zero, zero))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    row = jnp.where(lane == 0, jnp.sum(sv), jnp.where(lane == 1, jnp.sum(sa),
+                                                      0.0))
+    out[0, 0, pl.ds(k, 1), :] = row
 
 
 def normalize_chunk(block, chunk: int) -> int:
@@ -180,23 +204,60 @@ def normalize_chunk(block, chunk: int) -> int:
     return chunk
 
 
-def _restack(vol, bx, by, cz):
-    """Host-side overlapping brick view: (nbx, nby, nbz, BX+1, BY+1, CZ+1)."""
+def _corner_bricks(vol, bx, by, cz):
+    """Brick-major corner planes: (nbx, nby, nbz, 8, BX*BY*CZ).
+
+    Entry ``[i, j, k, c, l]`` is corner ``c`` (``mct.CORNERS``) of cell
+    ``l`` (x-major within the brick) of brick ``(i, j, k)``: the volume
+    zero-padded to whole bricks plus the closing plane, read at the eight
+    corner shifts.  Built by XLA once per call; the kernel then reads each
+    brick as one lane-dense (8, cells) block.
+    """
     nx, ny, nz = vol.shape
     nbx = max(1, -(-(nx - 1) // bx))
     nby = max(1, -(-(ny - 1) // by))
     nbz = max(1, -(-(nz - 1) // cz))
+    X, Y, Z = nbx * bx, nby * by, nbz * cz
     volp = jnp.pad(
-        vol,
-        ((0, nbx * bx + 1 - nx), (0, nby * by + 1 - ny), (0, nbz * cz + 1 - nz)),
+        vol, ((0, X + 1 - nx), (0, Y + 1 - ny), (0, Z + 1 - nz)),
         constant_values=0.0,
     )
-    ix = (np.arange(nbx)[:, None] * bx + np.arange(bx + 1)[None, :]).reshape(-1)
-    iy = (np.arange(nby)[:, None] * by + np.arange(by + 1)[None, :]).reshape(-1)
-    iz = (np.arange(nbz)[:, None] * cz + np.arange(cz + 1)[None, :]).reshape(-1)
-    v = volp[ix][:, iy][:, :, iz]
-    v = v.reshape(nbx, bx + 1, nby, by + 1, nbz, cz + 1)
-    return jnp.transpose(v, (0, 2, 4, 1, 3, 5)), (nbx, nby, nbz)
+    planes = []
+    for dx, dy, dz in np.asarray(mct.CORNERS):
+        c = volp[dx:dx + X, dy:dy + Y, dz:dz + Z]
+        c = c.reshape(nbx, bx, nby, by, nbz, cz).transpose(0, 2, 4, 1, 3, 5)
+        planes.append(c.reshape(nbx, nby, nbz, bx * by * cz))
+    return jnp.stack(planes, axis=3), (nbx, nby, nbz)
+
+
+def _brick_partials(vol, scal, block, chunk, interpret, z_scal):
+    """Run the brick kernel: per-brick (signed volume, area) partials.
+
+    Returns two (nbx, nby, nbz) arrays.  Each (i, j) brick column owns one
+    (nbz, 128) output block -- a block spanning the array's last two dims,
+    as Mosaic requires -- revisited along the sequential k sweep.
+    """
+    bx, by, cz = block
+    cells = bx * by * cz
+    chunk = normalize_chunk(block, chunk)
+    corners, (nbx, nby, nbz) = _corner_bricks(vol, bx, by, cz)
+    out = pl.pallas_call(
+        functools.partial(_mc_kernel, chunk=chunk, block=tuple(block),
+                          z_scal=z_scal),
+        grid=(nbx, nby, nbz),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((3 * _GROUP, 256), lambda i, j, k: (0, 0)),
+            pl.BlockSpec((8, cells), lambda i, j, k: (0, 0)),
+            pl.BlockSpec((1, 1, 1, 8, cells),
+                         lambda i, j, k: (i, j, k, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, nbz, 128),
+                               lambda i, j, k: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nbx, nby, nbz, 128), jnp.float32),
+        interpret=interpret,
+    )(scal, jnp.asarray(_TABLE), jnp.asarray(_cell_coords(block)), corners)
+    return out[..., 0], out[..., 1]
 
 
 @functools.partial(
@@ -216,9 +277,6 @@ def mc_volume_area_pallas(
     Matches ``kernels.ref.mc_volume_area`` (same table, same interpolation).
     """
     vol = jnp.asarray(vol, jnp.float32)
-    bx, by, cz = block
-    chunk = normalize_chunk(block, chunk)
-    bricks, (nbx, nby, nbz) = _restack(vol, bx, by, cz)
 
     # centre the coordinate origin to minimise f32 cancellation
     nx, ny, nz = vol.shape
@@ -226,26 +284,9 @@ def mc_volume_area_pallas(
     origin = -0.5 * jnp.asarray([nx, ny, nz], jnp.float32) * sp
     scal = jnp.concatenate([jnp.asarray([iso], jnp.float32), sp, origin])
 
-    out_spec = pl.BlockSpec((1, 1, 1), lambda i, j, k: (i, j, k))
-    vol_p, area_p = pl.pallas_call(
-        functools.partial(_mc_kernel, chunk=chunk),
-        grid=(nbx, nby, nbz),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((256, _NSLOTS), lambda i, j, k: (0, 0)),
-            pl.BlockSpec(
-                (1, 1, 1, bx + 1, by + 1, cz + 1),
-                lambda i, j, k: (i, j, k, 0, 0, 0),
-            ),
-        ],
-        out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((nbx, nby, nbz), jnp.float32),
-            jax.ShapeDtypeStruct((nbx, nby, nbz), jnp.float32),
-        ],
-        interpret=interpret,
-    )(scal, jnp.asarray(mct.TRI_TABLE, jnp.float32), bricks)
-    return jnp.abs(jnp.sum(vol_p)), jnp.sum(area_p)
+    vol_p, area_p = _brick_partials(vol, scal, block, chunk, interpret,
+                                    z_scal=False)
+    return mc_partials_finalize(vol_p, area_p)
 
 
 @functools.partial(
@@ -279,36 +320,28 @@ def mc_brick_partials_pallas(
     The window must span whole bricks: ``slab.shape[2] == k*cz + 1``.
     """
     slab = jnp.asarray(slab, jnp.float32)
-    bx, by, cz = block
-    chunk = normalize_chunk(block, chunk)
-    bricks, (nbx, nby, nbz) = _restack(slab, bx, by, cz)
-
     sp = jnp.asarray(spacing, jnp.float32)
     origin = -0.5 * jnp.asarray(list(full_shape), jnp.float32) * sp
     scal = jnp.concatenate([
         jnp.asarray([iso], jnp.float32), sp, origin,
         jnp.asarray([z_cell_offset], jnp.float32),
     ])
+    return _brick_partials(slab, scal, block, chunk, interpret, z_scal=True)
 
-    out_spec = pl.BlockSpec((1, 1, 1), lambda i, j, k: (i, j, k))
-    return pl.pallas_call(
-        functools.partial(_mc_kernel, chunk=chunk, z_scal=True),
-        grid=(nbx, nby, nbz),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((256, _NSLOTS), lambda i, j, k: (0, 0)),
-            pl.BlockSpec(
-                (1, 1, 1, bx + 1, by + 1, cz + 1),
-                lambda i, j, k: (i, j, k, 0, 0, 0),
-            ),
-        ],
-        out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((nbx, nby, nbz), jnp.float32),
-            jax.ShapeDtypeStruct((nbx, nby, nbz), jnp.float32),
-        ],
-        interpret=interpret,
-    )(scal, jnp.asarray(mct.TRI_TABLE, jnp.float32), bricks)
+
+def _tree_sum(x):
+    """Sum by pairwise halving over the flattened array (zero-padded).
+
+    Each level is one elementwise add, which XLA neither re-associates nor
+    vectorises differently from one program to the next -- unlike a
+    ``jnp.sum``, whose accumulation order depends on what it is fused with.
+    """
+    x = x.reshape(-1)
+    n = 1 << max(0, (x.size - 1).bit_length())
+    x = jnp.pad(x, (0, n - x.size))
+    while x.size > 1:
+        x = x[:x.size // 2] + x[x.size // 2:]
+    return x[0]
 
 
 @jax.jit
@@ -316,12 +349,12 @@ def mc_partials_finalize(vol_p, area_p):
     """Reduce assembled full-grid brick partials: (|sum vol|, sum area).
 
     The same two reductions :func:`mc_volume_area_pallas` ends with, over
-    an array of the same (nbx, nby, nbz) shape -- the reduction-tree
-    shape is what fixes the f32 accumulation order, so assembling tile
+    an array of the same (nbx, nby, nbz) shape.  The summation order is
+    fixed by the brick grid alone (:func:`_tree_sum`), so assembling tile
     partials into the full grid first keeps the result bit-identical to
-    the in-core pass.
+    the in-core pass, whichever program the reduction is compiled into.
     """
-    return jnp.abs(jnp.sum(vol_p)), jnp.sum(area_p)
+    return jnp.abs(_tree_sum(vol_p)), _tree_sum(area_p)
 
 
 @functools.partial(
@@ -365,5 +398,35 @@ def flop_estimate(shape, block=(8, 8, 8), chunk=512) -> float:
     bx, by, cz = block
     nbricks = (-(-(nx - 1) // bx)) * (-(-(ny - 1) // by)) * (-(-(nz - 1) // cz))
     cells = bx * by * cz
-    per_cell = 2 * 256 * _NSLOTS + _NSLOTS * 12 * (1 + 2 * 3) + mct.MAX_TRIS * 60
+    rows = 3 * _GROUP  # the (24, chunk) edge-id tile
+    # one-hot table matmul + 12-way compare/select of 3 coordinates +
+    # the per-triangle cross products
+    per_cell = 2 * 256 * rows + 12 * 4 * rows + _GROUP * 35
     return float(nbricks) * cells * per_cell
+
+
+# Device temporaries one brick-kernel call holds, per padded cell: the
+# (8, cells) corner planes (32 B) and the copies XLA's TPU layouts make of
+# the brick transposes that build them.  A bound on the v5e compiler's
+# ``memory_analysis()`` (up to 108 B at the shapes
+# tests/test_chip_compile.py compiles), not a published figure.
+WORK_BYTES_PER_CELL = 128
+# a brick whose edges are multiples of every autotune candidate's, so
+# its padding bounds theirs (runtime/autotune.DEFAULT_MC_BLOCKS)
+_BOUND_BLOCK = (16, 16, 16)
+
+
+def work_bytes(shape, block=_BOUND_BLOCK) -> int:
+    """Device temporaries of one brick-kernel call on a ``shape`` volume.
+
+    Scales with the cells padded to whole bricks (as in
+    ``_corner_bricks``); the default block bounds every autotune
+    candidate's padding.  Callers that budget device memory (the stream
+    window, the tiled engine) add it to their staged bytes: a batch runs
+    its cases one at a time (``lax.map``), so one call's temporaries are
+    alive at once.
+    """
+    cells = 1
+    for n, b in zip(shape, block):
+        cells *= max(1, -(-(n - 1) // b)) * b
+    return WORK_BYTES_PER_CELL * cells

@@ -9,7 +9,7 @@ importing this module never touches jax device state -- the dry-run sets
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -19,15 +19,15 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 
 
 def make_host_mesh(model_parallel: int = 1) -> Mesh:
-    """Mesh over whatever devices exist (tests / local runs)."""
+    """Mesh over whatever devices exist (tests / local runs).
+
+    The axes are ``Auto``: the extraction executor slices and re-stacks
+    data-sharded batches between its sharded launches, which explicit
+    axes (``jax.make_mesh``'s default) would require it to annotate.
+    """
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"))
+    return jax.make_mesh((n // model_parallel, model_parallel),
+                         ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
-
-HW = {
-    # TPU v5e per-chip constants used by the roofline analysis
-    "peak_flops_bf16": 197e12,  # FLOP/s
-    "hbm_bw": 819e9,  # B/s
-    "ici_bw": 50e9,  # B/s per link
-}

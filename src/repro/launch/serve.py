@@ -13,10 +13,15 @@ complete with a ``DeadlineExceeded`` error row instead of occupying a
 window slot); ``--queue-mb`` bounds the admission-control byte budget
 (submitters block on a full queue).  The gated benchmark twin of this
 demo is ``benchmarks/serve_latency.py``.
+
+Exit status: 0 when every request was answered, 1 when any case failed
+for a reason other than its deadline or an input quarantine (a window
+that died, see ``serve.service.WindowFailed``).
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import threading
 import time
 
@@ -24,6 +29,8 @@ import numpy as np
 
 from repro.core.pipeline import BatchedExtractor
 from repro.data.synthetic import mixed_traffic_stream
+from repro.runtime.compile_cache import use_compile_cache
+from repro.serve.service import WindowFailed
 
 
 def main(argv=None):
@@ -47,6 +54,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.smoke:
         args.clients, args.requests, args.huge_every = 2, 3, 5
+    use_compile_cache()
 
     bx = BatchedExtractor(backend=args.backend, prep="hint",
                           schedule="static", families=args.families)
@@ -103,10 +111,16 @@ def main(argv=None):
     if stats["expired_cases"]:
         print(f"[serve] {stats['expired_cases']} cases expired at "
               f"deadline {args.deadline_ms} ms")
-    if error_rows:
-        print(f"[serve] {len(error_rows)} error rows "
+    failed = [e for e in error_rows if e.startswith(WindowFailed.__name__)]
+    if len(error_rows) > len(failed):
+        print(f"[serve] {len(error_rows) - len(failed)} error rows "
               f"(deadline/quarantine)")
+    if failed:
+        print(f"[serve] FAILED: {len(failed)} cases lost to failed windows; "
+              f"first: {failed[0]}")
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -20,6 +20,7 @@ import numpy as np
 from repro.core.pipeline import BatchedExtractor
 from repro.core.tiled import TiledExtractor
 from repro.data.tiles import FnSlabSource, TiledCase
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def _blobby_case(shape=(36, 40, 150), seed=7):
@@ -42,6 +43,7 @@ def main(argv=None) -> int:
     ap.add_argument("--budget-kb", type=int, default=192,
                     help="forced staged-bytes budget (tiny => many tiles)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     budget = args.budget_kb * 1024
     t_start = time.perf_counter()
 

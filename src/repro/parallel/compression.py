@@ -54,8 +54,10 @@ def reduce_compressed(q, scale, axis_name=None):
     if axis_name is None:
         return qi.astype(jnp.float32) * scale
     total = jax.lax.psum(qi, axis_name)  # int32 wire-sum of int8 payloads
-    n = jax.lax.psum(jnp.ones((), jnp.int32), axis_name)
-    return total.astype(jnp.float32) * scale / n.astype(jnp.float32)
+    # the worker count is static: a psum of a constant would need an
+    # ambient mesh under an explicit-axes mesh (jax.make_mesh's default)
+    n = jax.lax.axis_size(axis_name)
+    return total.astype(jnp.float32) * scale / jnp.float32(n)
 
 
 def compressed_psum_tree(grads, err_tree, axis_name=None):

@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel import sharding
-
 
 def pipeline_stages(n_layers: int, n_stages: int):
     """Evenly partition layers into contiguous stages."""
@@ -125,7 +123,7 @@ def pipeline_forward(layer_fn, params_stacked, x, mesh, *, n_micro: int,
         params_stacked,
     )
 
-    shmap = sharding.shard_map_compat(
+    shmap = jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(
@@ -133,7 +131,7 @@ def pipeline_forward(layer_fn, params_stacked, x, mesh, *, n_micro: int,
             P(),  # microbatches replicated in; stage 0 reads them
         ),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )
     out = shmap(jax.tree.map(lambda p: p, staged), micro)
     return out.reshape(b, s, d)

@@ -62,27 +62,6 @@ class _Ctx(threading.local):
 _CTX = _Ctx()
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = True):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` (replication check kwarg
-    ``check_vma``); 0.4.x has it under ``jax.experimental.shard_map`` with
-    ``check_rep``.  All repo call sites go through this wrapper.  ``check``
-    defaults to True like jax itself; pass False only where the checker
-    rejects a legitimate program (e.g. the gpipe ppermute loop).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
-
-
 def data_parallel_map(fn, mesh: Mesh | None = None, axis: str = "data",
                       check: bool = True):
     """Shard a batched device function over ``axis`` of a mesh.
@@ -91,7 +70,7 @@ def data_parallel_map(fn, mesh: Mesh | None = None, axis: str = "data",
     same leading dimension (e.g. the pipeline's vmapped pass-1 pruning
     bound, the batched segmented compaction, or the staged pass-2a
     marching-cubes batch).  With a mesh the batch axis is split over
-    ``axis`` via :func:`shard_map_compat`, so N devices process N slices
+    ``axis`` via ``jax.shard_map``, so N devices process N slices
     concurrently; with no mesh (or a mesh without the axis) this is a
     plain ``jax.jit`` -- a strict no-op fallback, which is what lets the
     same pipeline code run on CPU and on a pod.  ``mesh`` defaults to the
@@ -104,8 +83,8 @@ def data_parallel_map(fn, mesh: Mesh | None = None, axis: str = "data",
         return jax.jit(fn)
     spec = PartitionSpec(axis)
     return jax.jit(
-        shard_map_compat(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                         check=check)
+        jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                      check_vma=check)
     )
 
 

@@ -582,6 +582,11 @@ def get_mc_config(
 # ---------------------------------------------------------------------------
 
 
+def compact_probe_cap(bucket: int) -> int:
+    """Output cap of the compaction probe: the ~25% keep fraction's bucket."""
+    return max(512, int(bucket) // 4)
+
+
 def measure_compact_config(
     bucket: int,
     backend: str,
@@ -608,7 +613,7 @@ def measure_compact_config(
     rng = np.random.default_rng(seed)
     verts = np.asarray(rng.normal(size=(batch, bucket, 3)) * 10.0, np.float32)
     keep = rng.random((batch, bucket)) < 0.25
-    cap = max(512, int(bucket) // 4)
+    cap = compact_probe_cap(bucket)
     kw = dispatcher.kernel_kwargs(backend)
 
     def call():
@@ -637,9 +642,18 @@ def sweep_compact(
     ``table`` maps ``str(block)`` to measured microseconds.  Blocks larger
     than the bucket only pad the grid, so they are dropped (the smallest
     candidate is clamped in when all are too big), mirroring the diameter
-    sweep's policy.
+    sweep's policy.  Blocks whose resident output row would not fit the
+    kernel's VMEM at the probe's cap (``kernels/compact.fits``) are
+    dropped before anything compiles.
     """
+    from repro.kernels import compact as ck
+
     usable = [b for b in blocks if b <= bucket] or [min(min(blocks), bucket)]
+    cap = compact_probe_cap(bucket)
+    usable = [b for b in usable if ck.fits(cap, b)]
+    if not usable:
+        raise ValueError(f"no compaction block fits the VMEM bound at "
+                         f"cap {cap}")
     table: dict[str, float] = {}
     best, best_t = None, float("inf")
     for block in usable:
@@ -950,21 +964,32 @@ def get_sync_cost(
 # hardware roofline profile (peak FLOP/s + memory bandwidth) probe
 # ---------------------------------------------------------------------------
 
-# Static per-backend fallback profiles, used when no ``hw/<backend>`` entry
-# exists and probing is disallowed.  The cost model only consumes RATIOS of
-# these numbers (compute-vs-memory bound, bucket-vs-bucket cost), so modest
-# order-of-magnitude figures suffice:
-#   pallas          -- v5e VPU f32 throughput + HBM bandwidth (the
+# Static fallback profiles, used when no ``hw/<backend>`` entry exists and
+# probing is disallowed.  The cost model only consumes RATIOS of these
+# numbers (compute-vs-memory bound, bucket-vs-bucket cost):
+#   pallas          -- the chip JAX runs on, from ``runtime/peaks``
+#                      (keyed by ``device_kind``; a chip with no entry is
+#                      an error): its MODELLED VPU f32 rate, because the
 #                      extraction kernels are elementwise/VPU work, not
-#                      MXU matmuls; see benchmarks/common.V5E)
+#                      MXU matmuls, and its published HBM bandwidth
 #   ref / interpret -- a single CPU core driving numpy-like jnp ops
 # Unknown backend strings have NO default profile: ``get_hw_profile``
 # returns None and the cost model falls back to its analytic constant.
 DEFAULT_HW_PROFILES = {
-    "pallas": {"peak_flops": 7.0e12, "mem_bw": 819.0e9, "source": "default"},
     "ref": {"peak_flops": 8.0e9, "mem_bw": 20.0e9, "source": "default"},
     "interpret": {"peak_flops": 8.0e9, "mem_bw": 20.0e9, "source": "default"},
 }
+
+
+def _default_hw_profile(backend: str) -> dict | None:
+    if backend != "pallas":
+        return DEFAULT_HW_PROFILES.get(backend)
+    from repro.runtime import peaks
+
+    p = peaks.device_peaks(jax.devices()[0].device_kind)
+    return {"peak_flops": p["vpu_flops_f32"], "mem_bw": p["hbm_bw"],
+            "source": "default"}
+
 
 HW_PROBE_MATMUL_N = 512   # f32 matmul edge for the peak-FLOP/s probe
 HW_PROBE_COPY_ELEMS = 1 << 22  # 16 MiB f32 stream for the bandwidth probe
@@ -1025,8 +1050,9 @@ def get_hw_profile(
     cache entry wins without running anything; a miss probes when allowed
     (same policy as the sync probe -- pallas by default,
     ``REPRO_AUTOTUNE=1`` forces, ``=0`` disables) and persists the
-    measurement; a disallowed probe returns the static
-    :data:`DEFAULT_HW_PROFILES` entry uncached.  Returns ``None`` -- "no
+    measurement; a disallowed probe returns the static default uncached
+    (:data:`DEFAULT_HW_PROFILES`, or the chip's ``runtime/peaks`` entry
+    for ``pallas``).  Returns ``None`` -- "no
     profile exists" -- under ``REPRO_ROOFLINE=0`` (the escape hatch back
     to the cost model's analytic constant) and for backend strings with
     no default profile when probing is disallowed.
@@ -1045,7 +1071,7 @@ def get_hw_profile(
             return {"peak_flops": peak, "mem_bw": bw,
                     "source": "measured"}
     if not _sync_probe_allowed(backend):
-        return DEFAULT_HW_PROFILES.get(backend)
+        return _default_hw_profile(backend)
     prof = measure_hw_profile(repeat=repeat)
     cache.put(
         hw_key(backend),

@@ -36,7 +36,10 @@ Decisions served (wired through ``core/executor``):
     past its break-even depth (a fresh singleton bucket would only
     fragment a healthy window); extend homogeneous runs until the
     memory-budgeted cap (``REPRO_STREAM_MEM_MB``, default 512 MiB of
-    staged masks + vertex stacks) or the absolute case cap.
+    staged masks + vertex stacks, plus -- on the backends that run the
+    marching-cubes brick kernel -- the device temporaries of the
+    window's largest MC call, ``kernels/marching_cubes.work_bytes``) or
+    the absolute case cap.
 
 ``break_even_depth(cap)``
     The smallest power-of-two sub-batch depth whose measured per-case
@@ -91,6 +94,7 @@ import os
 import warnings
 
 from repro.core import plan as planlib
+from repro.kernels import marching_cubes as mck
 from repro.runtime import autotune
 from repro.runtime import roofline as rooflib
 
@@ -359,6 +363,22 @@ class CostModel:
                               int(self.window_mem_bytes // per_case)))
         return self.window_max_cases
 
+    def mc_work_bytes(self, census: planlib.WindowCensus,
+                      meta: planlib.CaseMeta) -> int:
+        """Device temporaries of the largest MC call once ``meta`` joins.
+
+        The brick kernel's corner planes are several times the staged
+        mask; its batch maps cases one at a time, so only the largest
+        shape counts.  Zero on ``ref``, whose marching cubes is the jnp
+        z-slab scan.
+        """
+        if self.backend == "ref":
+            return 0
+        shapes = list(census.shape_depths)
+        if not meta.empty:
+            shapes.append(meta.shape)
+        return max((mck.work_bytes(s) for s in shapes), default=0)
+
     def should_close(self, census: planlib.WindowCensus,
                      meta: planlib.CaseMeta) -> bool:
         """Close the open window before admitting ``meta``?
@@ -374,7 +394,8 @@ class CostModel:
             return False
         if census.cases >= self.window_budget_cases(census):
             return True
-        if census.bytes + planlib.meta_bytes(meta) > self.window_mem_bytes:
+        if (census.bytes + planlib.meta_bytes(meta)
+                + self.mc_work_bytes(census, meta) > self.window_mem_bytes):
             return True
         if not census.fragments(meta):
             return False

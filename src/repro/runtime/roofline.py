@@ -71,8 +71,8 @@ CAL = {
     "compact": {"flops_m": 25.04, "flops_cap": 1.0,
                 "bytes_m": 36.71, "bytes_cap": 13.0},
     "mc": {"flops": 773.0, "bytes": 2035.0},
-    "firstorder": {"flops": 226.0, "bytes": 338.0},
-    "glcm": {"flops": 51.3, "bytes": 92.6},
+    "firstorder": {"flops": 226.0, "bytes": 420.8},
+    "glcm": {"flops": 55.0, "bytes": 117.1},
 }
 
 MC_CHUNK_Z = 32  # the ref backend's z-slab scan chunk (kernels/ops.py)

@@ -89,6 +89,15 @@ class DeadlineExceeded(ServiceError):
     """The request's deadline passed before its cases reached a window."""
 
 
+class WindowFailed(ServiceError):
+    """The window holding the case died (past any retry policy).
+
+    Unlike a deadline or an input quarantine this is a failure of the
+    service itself: its error rows read ``"WindowFailed: <cause>"`` and
+    are counted as ``failed_cases``.
+    """
+
+
 DEFAULT_MAX_QUEUE_MB = 256.0
 # byte charge for a lazy loader case whose shape is unknown at admission
 # (callers that know their shapes pass ``shape_hints=``); sized like a
@@ -260,6 +269,7 @@ class ExtractionService:
         self._served_cases = 0
         self._expired_cases = 0
         self._quarantined_cases = 0
+        self._failed_cases = 0
         self._requests = 0
 
         self._driver = threading.Thread(
@@ -336,6 +346,7 @@ class ExtractionService:
                 "served_cases": self._served_cases,
                 "expired_cases": self._expired_cases,
                 "quarantined_cases": self._quarantined_cases,
+                "failed_cases": self._failed_cases,
                 "windows": len(self._windows),
                 "window_cases": [n for n, _ in self._windows],
                 "window_tenants": [t for _, t in self._windows],
@@ -401,6 +412,8 @@ class ExtractionService:
                 self._served_cases += 1
             elif error.startswith("DeadlineExceeded"):
                 self._expired_cases += 1
+            elif error.startswith(WindowFailed.__name__):
+                self._failed_cases += 1
             else:
                 self._served_cases += 1
                 self._quarantined_cases += 1
@@ -431,6 +444,7 @@ class ExtractionService:
                 # fail ITS requests, not the service
                 for req, ci in recs:
                     self._resolve(req, ci, None,
+                                  f"{WindowFailed.__name__}: "
                                   f"{type(e).__name__}: {e}")
                 return
             errors = stats.get("errors", {})

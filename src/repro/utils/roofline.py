@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import re
 
-from repro.launch.mesh import HW
+from repro.runtime.peaks import V5E as HW
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -232,9 +232,9 @@ def cost_terms(compiled, n_chips: int, model_flops: float | None = None,
                hw: dict | None = None) -> dict:
     """The roofline report for one compiled executable.
 
-    ``hw`` overrides the static mesh constants with a measured hardware
-    profile (``peak_flops_bf16`` / ``hbm_bw`` / ``ici_bw`` keys; missing
-    keys fall back to the mesh defaults) -- see
+    ``hw`` overrides the published v5e peaks (``runtime/peaks``) with a
+    measured hardware profile (``peak_flops_bf16`` / ``hbm_bw`` /
+    ``ici_bw`` keys; missing keys fall back to the published ones) -- see
     ``repro.runtime.autotune.get_hw_profile``.
     """
     raw_flops, raw_bytes = compiled_cost(compiled)

@@ -18,7 +18,6 @@ SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.parallel import compression as comp
-    from repro.parallel.sharding import shard_map_compat
 
     mesh = jax.make_mesh((4,), ("data",))
     rng = np.random.default_rng(0)
@@ -29,9 +28,15 @@ SCRIPT = textwrap.dedent(
                                             axis_name="data")
         return out["g"], ne["g"]
 
-    shmap = shard_map_compat(sync, mesh=mesh,
-                             in_specs=(P("data"), P("data")),
-                             out_specs=(P("data"), P("data")))
+    # jax.make_mesh builds explicit axes, whose shard_map bodies need the
+    # mesh in context
+    step_fn = jax.jit(jax.shard_map(sync, mesh=mesh,
+                                    in_specs=(P("data"), P("data")),
+                                    out_specs=(P("data"), P("data"))))
+
+    def shmap(g, e):
+        with jax.set_mesh(mesh):
+            return step_fn(g, e)
 
     err = jnp.zeros((4, 64), jnp.float32)
     acc = jnp.zeros((64,), jnp.float32)

@@ -374,6 +374,31 @@ def test_plan_census_and_meta_bytes():
     assert c.cap_depths == {4096: 2}
 
 
+@pytest.mark.parametrize("backend", ["ref", "interpret", "pallas"])
+def test_window_budget_counts_brick_mc_temporaries(backend):
+    from repro.kernels import marching_cubes as mck
+    m = planlib.CaseMeta((64, 64, 64), (50, 50, 50), 4096, 3000)
+    work = mck.work_bytes(m.shape)
+    # two cases' staged bytes fit the budget; the MC temporaries do not
+    budget = 2 * planlib.meta_bytes(m) + work // 2
+    cm = costmodel.CostModel(backend, cache=autotune.AutotuneCache(),
+                             window_mem_bytes=budget)
+    c = planlib.WindowCensus()
+    c.add(m)
+    brick = backend != "ref"
+    assert cm.mc_work_bytes(c, m) == (work if brick else 0)
+    assert cm.should_close(c, m) == brick
+
+
+@pytest.mark.parametrize("shape", [(32, 32, 32), (100, 70, 51),
+                                   (352, 128, 224)], ids=str)
+def test_mc_work_bound_covers_every_candidate_block(shape):
+    from repro.kernels import marching_cubes as mck
+    bound = mck.work_bytes(shape)
+    for block in autotune.DEFAULT_MC_BLOCKS:
+        assert mck.work_bytes(shape, block) <= bound
+
+
 def test_env_float_warns_once_on_malformed(monkeypatch):
     import warnings
 

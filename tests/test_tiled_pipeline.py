@@ -206,6 +206,23 @@ def test_budget_accounting_and_env_default(monkeypatch):
     assert tx.budget_bytes == 64 * 2**20
 
 
+def test_budget_counts_brick_mc_temporaries():
+    # on the brick kernel a tile's MC temporaries (several times its slab)
+    # share the budget with the two staged tiles; ref stages slabs only
+    image, mask = _ellipsoid(shape=(26, 28, 44), radii=(8, 9, 15))
+    budget = 3 << 20
+    ref = _tiled_row(PlanExecutor(backend="ref"), None, mask, budget)
+    assert ref.stats["mc_work_bytes"] == 0
+    ex = PlanExecutor(backend="interpret")
+    res = _tiled_row(ex, None, mask, budget)
+    st = res.stats
+    assert st["mc_work_bytes"] > st["staged_bytes_peak"] > 0
+    assert st["staged_bytes_peak"] + st["mc_work_bytes"] <= budget
+    assert st["tiles"] >= 2
+    assert st["granules_per_tile"] < ref.stats["granules_per_tile"]
+    np.testing.assert_array_equal(ex.extract_one(None, mask, SP), res.row)
+
+
 def test_over_budget_minimum_tile_warns():
     mask = np.zeros((40, 44, 57), np.float32)
     mask[4:36, 4:40, 4:53] = 1.0
@@ -310,6 +327,12 @@ def test_default_extractor_leaves_tuples_incore():
     assert bx._route_tiled(TiledCase(mask, spacing=SP))
     bxt = BatchedExtractor(backend="ref", tiled=True, tile_mem_mb=0.01)
     assert bxt._route_tiled((image, mask, SP))
+    # the staged mask (125 KiB) fits 1 MiB; off ref, its marching-cubes
+    # temporaries do not
+    assert not BatchedExtractor(backend="ref", tiled=True, tile_mem_mb=1.0
+                                )._route_tiled((image, mask, SP))
+    assert BatchedExtractor(backend="interpret", tiled=True, tile_mem_mb=1.0
+                            )._route_tiled((image, mask, SP))
 
 
 # -- the out-of-core acceptance case ----------------------------------------
