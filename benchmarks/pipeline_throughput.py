@@ -261,7 +261,6 @@ def run(n_cases: int = 12, records=None, repeat: int = 8):
                     vertex_buckets=stats["vertex_buckets"],
                     pruned_cases=stats["pruned_cases"],
                     mean_keep_fraction=stats["mean_keep_fraction"],
-                    prune_seconds=stats["prune_seconds"],
                 )
             records.append(rec)
 

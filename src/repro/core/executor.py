@@ -139,7 +139,7 @@ class _Window:
     diam_futs: list
     fused_futs: list
     static_aux: list  # [(cap, idxs, counts_fut, verts, masks)] to resolve
-    t_prune: float
+    seq: int  # the executor's window number: the ``window`` stat of its spans
     family_futs: dict = dataclasses.field(default_factory=dict)
     # {family: [(idxs, future)]} -- the intensity-family launches
 
@@ -242,6 +242,7 @@ class PlanExecutor:
         self._transfer_cb = transfer_callback
         self.retry = retry  # runtime/resilience.RetryPolicy (duck-typed)
         self.window_retries = 0  # collect retries performed (resilience census)
+        self._next_window = 0  # number the next submitted window gets
         self._compiled = {}
 
     @property
@@ -271,7 +272,9 @@ class PlanExecutor:
         self.transfer_log[stage] += 1
         if self._transfer_cb is not None:
             self._transfer_cb(stage, x)
-        return np.asarray(x)
+        with jax.profiler.TraceAnnotation("repro.fetch", stage=stage,
+                                          bytes=x.nbytes):
+            return np.asarray(x)
 
     # -- tuned-config resolution (outside any trace) ------------------------
 
@@ -308,14 +311,17 @@ class PlanExecutor:
 
     # -- compiled-function cache -------------------------------------------
 
-    def _dp_map(self, fn, check: bool = True):
+    def _dp_map(self, fn, name: str, check: bool = True):
         """Shard a batched fn over the data axis (plain jit without a mesh).
 
-        ``check=False`` for batch fns that contain a ``pallas_call``:
-        jax's shard_map replication checker has no rule for it (the
-        documented workaround -- results are still bit-identical, locked
-        by tests/test_pipeline_multidevice.py).
+        ``name`` names the program: ``jit_<name>`` in a profiler trace and
+        ``jit(<name>)`` in the compile events, so device time and compiles
+        are told apart by pass.  ``check=False`` for batch fns that contain
+        a ``pallas_call``: jax's shard_map replication checker has no rule
+        for it (the documented workaround -- results are still
+        bit-identical, locked by tests/test_pipeline_multidevice.py).
         """
+        fn.__name__ = fn.__qualname__ = name
         return psharding.data_parallel_map(
             fn, self.mesh, self.data_axis, check=check
         )
@@ -343,7 +349,7 @@ class PlanExecutor:
             # transfer with no eager stitching (batch dim first: shardable)
             return keep, jnp.stack([m_valid, m_kept], axis=1)
 
-        fn = self._dp_map(batch)
+        fn = self._dp_map(batch, "pass1_bound")
         self._compiled[key] = fn
         return fn
 
@@ -361,7 +367,7 @@ class PlanExecutor:
             )
             return v, m
 
-        fn = self._dp_map(batch, check=False)
+        fn = self._dp_map(batch, "pass1_compact", check=False)
         self._compiled[key] = fn
         return fn
 
@@ -390,7 +396,7 @@ class PlanExecutor:
             )
             return v, m, jnp.stack([m_valid, m_kept], axis=1)
 
-        fn = self._dp_map(batch, check=False)
+        fn = self._dp_map(batch, "pass1_static", check=False)
         self._compiled[key] = fn
         return fn
 
@@ -411,7 +417,7 @@ class PlanExecutor:
         def batch(masks, spacings):
             return jax.lax.map(one, (masks, spacings))
 
-        fn = self._dp_map(batch, check=False)
+        fn = self._dp_map(batch, "fused_one_pass", check=False)
         self._compiled[key] = fn
         return fn
 
@@ -433,7 +439,7 @@ class PlanExecutor:
                 block=mc_block, chunk=mc_chunk,
             )
 
-        fn = self._dp_map(batch, check=False)
+        fn = self._dp_map(batch, "pass2a_mc", check=False)
         self._compiled[key] = fn
         return fn
 
@@ -461,7 +467,7 @@ class PlanExecutor:
                 return op(images, masks, backend=backend, n_bins=n_bins,
                           block=block)
 
-            fn = self._dp_map(batch, check=False)
+            fn = self._dp_map(batch, f"family_{family}", check=False)
             self._compiled[key] = fn
             return fn
 
@@ -484,13 +490,14 @@ class PlanExecutor:
         def batch(verts, vmasks):
             return jax.lax.map(one, (verts, vmasks))
 
-        fn = self._dp_map(batch, check=False)
+        fn = self._dp_map(batch, "pass2b_diameter", check=False)
         self._compiled[key] = fn
         return fn
 
     # -- submit/drain drivers ----------------------------------------------
 
-    def _submit(self, entries, fn_for_key, make_chunk, batch_size=None):
+    def _submit(self, stage, entries, fn_for_key, make_chunk,
+                batch_size=None):
         """Submit every chunk of every entry; returns ``[(idxs, future)]``.
 
         ``entries`` yields ``(compile key, case indices, payload)``;
@@ -499,17 +506,21 @@ class PlanExecutor:
         of the mesh's data-axis size, so shard_map shapes stay uniform).
         jax dispatch is async, so every launch of the window is queued
         before any result is fetched -- the transfer/compute of chunk k+1
-        overlaps chunk k, and draining is the collector's job.
+        overlaps chunk k, and draining is the collector's job.  The
+        dispatches run inside a ``repro.launch.<stage>`` span.
         """
         n_data = psharding.axis_size(self.mesh, self.data_axis)
         futs = []
-        for gkey, idxs, payload in entries:
-            bs = batch_size or max(n_data, len(idxs))
-            bs = int(math.ceil(bs / n_data)) * n_data
-            fn = fn_for_key(gkey, autotune.batch_bucket(bs))
-            for s in range(0, len(idxs), bs):
-                chunk = idxs[s : s + bs]
-                futs.append((chunk, fn(*make_chunk(payload, s, chunk, bs))))
+        with jax.profiler.TraceAnnotation(f"repro.launch.{stage}") as span:
+            for gkey, idxs, payload in entries:
+                bs = batch_size or max(n_data, len(idxs))
+                bs = int(math.ceil(bs / n_data)) * n_data
+                fn = fn_for_key(gkey, autotune.batch_bucket(bs))
+                for s in range(0, len(idxs), bs):
+                    chunk = idxs[s : s + bs]
+                    futs.append(
+                        (chunk, fn(*make_chunk(payload, s, chunk, bs))))
+            span.set_metadata(launches=len(futs))
         return futs
 
     def _drain(self, futs, stage: str) -> dict:
@@ -593,7 +604,7 @@ class PlanExecutor:
                 for shape, idxs in plan.shape_groups.items()
             ]
             futs[family] = self._submit(
-                entries, self._family_fn(family), self._stacked_chunk,
+                family, entries, self._family_fn(family), self._stacked_chunk,
                 batch_size,
             )
         return futs
@@ -602,22 +613,17 @@ class PlanExecutor:
 
     def _prep_case(self, image, mask, spacing, fields: bool = True,
                    prep: str | None = None) -> _Prepped:
-        """Crop, bucket-pad, device-stage, and compact one case (pass 0).
+        """Crop, bucket-pad, device-stage, and compact one case (pass 0):
+        :meth:`_crop_case` on the host, then :meth:`_stage_case`."""
+        return self._stage_case(self._crop_case(image, mask, spacing),
+                                fields=fields, prep=prep)
 
-        ``fields=False`` (the legacy one-pass path, which recomputes the
-        vertex field inside its fused kernel) skips the field/count
-        launches and sizes the cap from the metadata hint
-        (``plan.vertex_hint`` -- memoised, spacing-aware).
+    def _crop_case(self, image, mask, spacing) -> _Prepped:
+        """Host half of pass 0: crop to the ROI and pad to its shape bucket.
 
-        ``prep`` (default: the executor's configured prep) sizes the M
-        cap: ``'count'`` fetches the measured dedup count (one ``int(n)``
-        host sync per case -- the parity baseline), ``'hint'`` sizes it
-        from ``plan.vertex_hint`` metadata alone and leaves the true
-        count ON DEVICE (``n_fut``) for the collector -- pass 0 becomes
-        sync-free, at the cost of occasional over-allocation plus the
-        rare hint-overflow retry (``_resolve_hint_counts``).
+        The result's ``mask``/``image`` are still host arrays (``None``
+        mask: an empty-mask case, which stays an all-zero feature row).
         """
-        prep = prep or self.prep
         sp = np.asarray(spacing, np.float32)
         if not np.any(mask):
             return _Prepped(spacing=sp)  # empty mask: all-zero feature row
@@ -637,51 +643,77 @@ class PlanExecutor:
         roi_shape = m.shape
         bshape = planlib.shape_bucket(tuple(s - 2 for s in roi_shape))
         pad = [(0, bs - ms) for bs, ms in zip(bshape, roi_shape)]
-        mdev = jnp.asarray(np.pad(m, pad))  # staged once; pool entry
-        idev = (jnp.asarray(np.pad(im, pad)) if self._needs_intensity
-                else None)  # staged once; shared by every intensity family
+        return _Prepped(
+            mask=np.pad(m, pad), spacing=sp, shape=bshape, roi_shape=roi_shape,
+            image=np.pad(im, pad) if self._needs_intensity else None,
+        )
+
+    def _stage_case(self, p: _Prepped, fields: bool = True,
+                    prep: str | None = None) -> _Prepped:
+        """Device half of pass 0: stage the padded volumes, then size the
+        case's vertex cap.
+
+        ``fields=False`` (the legacy one-pass path, which recomputes the
+        vertex field inside its fused kernel) skips the field/count
+        launches and sizes the cap from the metadata hint
+        (``plan.vertex_hint`` -- memoised, spacing-aware).
+
+        ``prep`` (default: the executor's configured prep) sizes the M
+        cap: ``'count'`` fetches the measured dedup count (one ``int(n)``
+        host sync per case -- the parity baseline), ``'hint'`` sizes it
+        from ``plan.vertex_hint`` metadata alone and leaves the true
+        count ON DEVICE (``n_fut``) for the collector -- pass 0 becomes
+        sync-free, at the cost of occasional over-allocation plus the
+        rare hint-overflow retry (``_resolve_hint_counts``).
+        """
+        if p.mask is None:
+            return p
+        prep = prep or self.prep
+        staged = p.mask.nbytes + (0 if p.image is None else p.image.nbytes)
+        with jax.profiler.TraceAnnotation("repro.prep.stage", bytes=staged):
+            # staged once: the pool entries, the image shared by every
+            # intensity family
+            p.mask = jnp.asarray(p.mask)
+            if p.image is not None:
+                p.image = jnp.asarray(p.image)
         if not self._shape_on:
             # intensity-only request: no vertex stage runs at all -- the
             # shape bucket still keys the family launches
-            return _Prepped(mask=mdev, image=idev, spacing=sp, shape=bshape,
-                            roi_shape=roi_shape)
+            return p
         if not fields:
-            hint = planlib.vertex_hint(tuple(s - 2 for s in roi_shape), sp)
-            return _Prepped(
-                mask=mdev, image=idev, spacing=sp, shape=bshape,
-                roi_shape=roi_shape,
-                n_vertices=hint,  # pad-waste census only (the fused kernel
-                vertex_cap=ops.vertex_bucket(hint),  # recounts for the row)
-            )
-        f, n = _fields_count(mdev, jnp.asarray(sp))
-        if prep == "hint":
-            # sync-free prep: the cap comes from metadata alone; the true
-            # count stays a device future the collector drains.  A larger-
-            # than-needed cap is harmless (pruning and the pair sweep are
-            # padding-invariant, tier-1-locked); a SMALLER one drops
-            # vertices, which the collector detects and retries count-sized.
-            hint = planlib.vertex_hint(tuple(s - 2 for s in roi_shape), sp)
-            cap = ops.vertex_bucket(hint)
-            verts, vmask = _compact_cap(f, cap)
-            return _Prepped(
-                mask=mdev, image=idev, spacing=sp, shape=bshape,
-                roi_shape=roi_shape, verts=verts, vmask=vmask,
-                n_vertices=hint, vertex_cap=cap, n_fut=n, prep_cap=cap,
-            )
-        n = int(self._fetch("prep", n))
-        cap = ops.vertex_bucket(n)
-        verts, vmask = _compact_cap(f, cap)
-        if not self.device_compact:  # PR 2 host path: pull to numpy per case
-            verts = self._fetch("prep", verts)
-            vmask = self._fetch("prep", vmask)
-        return _Prepped(
-            mask=mdev, image=idev, spacing=sp, shape=bshape,
-            roi_shape=roi_shape, verts=verts, vmask=vmask, n_vertices=n,
-            vertex_cap=cap,
-        )
+            p.n_vertices = planlib.vertex_hint(
+                tuple(s - 2 for s in p.roi_shape), p.spacing)
+            # pad-waste census only (the fused kernel recounts for the row)
+            p.vertex_cap = ops.vertex_bucket(p.n_vertices)
+            return p
+        with jax.profiler.TraceAnnotation("repro.prep.fields") as span:
+            f, n = _fields_count(p.mask, jnp.asarray(p.spacing))
+            if prep == "hint":
+                # sync-free prep: the cap comes from metadata alone; the
+                # true count stays a device future the collector drains.  A
+                # larger-than-needed cap is harmless (pruning and the pair
+                # sweep are padding-invariant, tier-1-locked); a SMALLER one
+                # drops vertices, which the collector detects and retries
+                # count-sized.
+                p.n_vertices = planlib.vertex_hint(
+                    tuple(s - 2 for s in p.roi_shape), p.spacing)
+                p.n_fut = n
+                cap = p.prep_cap = ops.vertex_bucket(p.n_vertices)
+            else:
+                p.n_vertices = n = int(self._fetch("prep", n))
+                cap = ops.vertex_bucket(n)
+            p.verts, p.vmask = _compact_cap(f, cap)
+            if prep != "hint" and not self.device_compact:
+                # host compaction path: pull to numpy per case
+                p.verts = self._fetch("prep", p.verts)
+                p.vmask = self._fetch("prep", p.vmask)
+            p.vertex_cap = cap
+            span.set_metadata(cap=cap)
+        return p
 
     def _prep_case_safe(self, case, fields: bool = True,
-                        prep: str | None = None) -> _Prepped:
+                        prep: str | None = None, window: int = -1,
+                        pos: int = -1) -> _Prepped:
         """Quarantining wrapper around :meth:`_prep_case` (pass 0).
 
         ``case`` is an ``(image, mask, spacing)`` tuple or a zero-arg
@@ -694,23 +726,34 @@ class PlanExecutor:
         on one poisoned segmentation (the row-level-error contract,
         tier-1-locked).  Validation and quarantine are pure host work:
         the sync-free submit path's zero-fetch invariants are untouched.
+
+        The case runs inside a ``repro.prep`` span whose ``window`` and
+        ``case`` stats are the window it is prepped for and its position
+        there (-1: prepped before its window is formed).
         """
-        try:
-            if callable(case):
-                case = case()
-            image, mask, spacing = case
-            m = np.asarray(mask)
-            if np.issubdtype(m.dtype, np.floating) and not np.isfinite(m).all():
-                raise ValueError("non-finite mask (poisoned case)")
-            sp = np.asarray(spacing, np.float64)
-            if sp.shape != (3,) or not np.isfinite(sp).all() or (sp <= 0).any():
-                raise ValueError(f"invalid spacing {spacing!r}")
-            return self._prep_case(image, mask, spacing, fields=fields,
-                                   prep=prep)
-        except (KeyboardInterrupt, SystemExit):
-            raise
-        except Exception as e:
-            return _Prepped(error=f"{type(e).__name__}: {e}")
+        with jax.profiler.TraceAnnotation("repro.prep", window=window,
+                                          case=pos):
+            try:
+                with jax.profiler.TraceAnnotation("repro.prep.crop") as span:
+                    if callable(case):
+                        case = case()
+                    image, mask, spacing = case
+                    m = np.asarray(mask)
+                    if (np.issubdtype(m.dtype, np.floating)
+                            and not np.isfinite(m).all()):
+                        raise ValueError("non-finite mask (poisoned case)")
+                    sp = np.asarray(spacing, np.float64)
+                    if (sp.shape != (3,) or not np.isfinite(sp).all()
+                            or (sp <= 0).any()):
+                        raise ValueError(f"invalid spacing {spacing!r}")
+                    p = self._crop_case(image, mask, spacing)
+                    span.set_metadata(
+                        voxels=0 if p.mask is None else p.mask.size)
+                return self._stage_case(p, fields=fields, prep=prep)
+            except (KeyboardInterrupt, SystemExit):
+                raise
+            except Exception as e:
+                return _Prepped(error=f"{type(e).__name__}: {e}")
 
     def _meta(self, p: _Prepped) -> planlib.CaseMeta:
         if p.mask is None:
@@ -741,16 +784,18 @@ class PlanExecutor:
 
     def _prune_pass(self, plan, prepped):
         """Pass 1 (host path): vmapped bound + per-case host compaction."""
-        for _, idxs in plan.cap_groups.items():
-            batch = ops.prune_candidates_batch(
-                np.stack([prepped[i].verts for i in idxs]),
-                np.stack([prepped[i].vmask for i in idxs]),
-                k_dirs=self.k_dirs,
-            )
-            for i, (v2, m2, info) in zip(idxs, batch):
-                prepped[i].verts, prepped[i].vmask = v2, m2
-                prepped[i].vertex_cap = len(v2)
-                prepped[i].prune_info = info
+        with jax.profiler.TraceAnnotation(
+                "repro.launch.pass1", launches=len(plan.cap_groups)):
+            for _, idxs in plan.cap_groups.items():
+                batch = ops.prune_candidates_batch(
+                    np.stack([prepped[i].verts for i in idxs]),
+                    np.stack([prepped[i].vmask for i in idxs]),
+                    k_dirs=self.k_dirs,
+                )
+                for i, (v2, m2, info) in zip(idxs, batch):
+                    prepped[i].verts, prepped[i].vmask = v2, m2
+                    prepped[i].vertex_cap = len(v2)
+                    prepped[i].prune_info = info
 
     def _pass1_counted(self, plan, prepped):
         """Pass 1 (counted device path): sharded bound + device compaction.
@@ -764,58 +809,66 @@ class PlanExecutor:
         so the two paths stay bit-identical.  Returns the pass-2b feed:
         ``[(M' bucket, case indices, (verts, vmask) stacks)]``.
         """
-        entries = []
-        for cap, idxs in plan.cap_groups.items():
-            b = len(idxs)
-            depth = autotune.batch_bucket(b)
-            verts, masks = self._pad_batch(
-                (
-                    jnp.stack([prepped[i].verts for i in idxs]),
-                    jnp.stack([prepped[i].vmask for i in idxs]),
-                ),
-                b,
-            )
-            keep, counts = self._bound_fn(cap, depth)(verts, masks)
-            # the one host sync of counted pass 1: a small (B, 2) matrix
-            counts = self._fetch("pass1", counts)
-            plans = [
-                prune_kernels.plan_compaction(
-                    cap, int(counts[j, 0]), int(counts[j, 1]),
-                    ops.vertex_bucket,
+        with jax.profiler.TraceAnnotation("repro.launch.pass1") as span:
+            launches = 0
+            entries = []
+            for cap, idxs in plan.cap_groups.items():
+                b = len(idxs)
+                depth = autotune.batch_bucket(b)
+                verts, masks = self._pad_batch(
+                    (
+                        jnp.stack([prepped[i].verts for i in idxs]),
+                        jnp.stack([prepped[i].vmask for i in idxs]),
+                    ),
+                    b,
                 )
-                for j in range(b)
-            ]
-            for j, i in enumerate(idxs):
-                prepped[i].prune_info = plans[j][1]
-                prepped[i].vertex_cap = plans[j][0] or cap
-            # keep-originals cases feed pass 2 at their input cap
-            groups = planlib.group_indices(
-                [cap_out if cap_out else ("orig", cap) for cap_out, _ in plans]
-            )
-            for gkey, js in groups.items():
-                # whole cap group agreeing on one target reuses the stacks
-                take = (
-                    None if len(js) == b
-                    else jnp.asarray(np.asarray(js, np.int32))
+                keep, counts = self._bound_fn(cap, depth)(verts, masks)
+                launches += 1
+                # the one host sync of counted pass 1: a small (B, 2) matrix
+                counts = self._fetch("pass1", counts)
+                plans = [
+                    prune_kernels.plan_compaction(
+                        cap, int(counts[j, 0]), int(counts[j, 1]),
+                        ops.vertex_bucket,
+                    )
+                    for j in range(b)
+                ]
+                for j, i in enumerate(idxs):
+                    prepped[i].prune_info = plans[j][1]
+                    prepped[i].vertex_cap = plans[j][0] or cap
+                # keep-originals cases feed pass 2 at their input cap
+                groups = planlib.group_indices(
+                    [cap_out if cap_out else ("orig", cap)
+                     for cap_out, _ in plans]
                 )
-
-                def sub(*arrays):
-                    if take is None:
-                        return arrays
-                    return self._pad_batch(
-                        tuple(jnp.take(a, take, axis=0) for a in arrays),
-                        len(js),
+                for gkey, js in groups.items():
+                    # whole cap group agreeing on one target reuses the stacks
+                    take = (
+                        None if len(js) == b
+                        else jnp.asarray(np.asarray(js, np.int32))
                     )
 
-                gidxs = [idxs[j] for j in js]
-                if isinstance(gkey, tuple):  # unpruned: originals, input cap
-                    entries.append((cap, gidxs, sub(verts, masks)))
-                    continue
-                # the launch carries the SUBGROUP's depth, not the cap group's
-                cv, cm = self._compact_fn(
-                    cap, gkey, autotune.batch_bucket(len(js))
-                )(*sub(verts, keep))
-                entries.append((gkey, gidxs, (cv, cm)))
+                    def sub(*arrays):
+                        if take is None:
+                            return arrays
+                        return self._pad_batch(
+                            tuple(jnp.take(a, take, axis=0) for a in arrays),
+                            len(js),
+                        )
+
+                    gidxs = [idxs[j] for j in js]
+                    if isinstance(gkey, tuple):
+                        # unpruned: originals, input cap
+                        entries.append((cap, gidxs, sub(verts, masks)))
+                        continue
+                    # the launch carries the SUBGROUP's depth, not the
+                    # cap group's
+                    cv, cm = self._compact_fn(
+                        cap, gkey, autotune.batch_bucket(len(js))
+                    )(*sub(verts, keep))
+                    launches += 1
+                    entries.append((gkey, gidxs, (cv, cm)))
+            span.set_metadata(launches=launches)
         return entries, []
 
     def _pass1_static(self, plan, prepped):
@@ -829,32 +882,35 @@ class PlanExecutor:
         counted schedule always keeps at their original cap) skip the
         chain entirely and feed pass 2b their original stacks.
         """
-        entries, aux = [], []
-        for cap, idxs in plan.cap_groups.items():
-            b = len(idxs)
-            target = plan.static_targets[cap]
-            verts, masks = self._pad_batch(
-                (
-                    jnp.stack([prepped[i].verts for i in idxs]),
-                    jnp.stack([prepped[i].vmask for i in idxs]),
-                ),
-                b,
-            )
-            if target is None:
-                # counted parity without the bound: a floor-cap group can
-                # never re-bucket, so its PruneInfo is metadata-only
-                for i in idxs:
-                    n = prepped[i].n_vertices
-                    prepped[i].prune_info = prune_kernels.PruneInfo(
-                        cap, n, n, False
-                    )
-                    prepped[i].vertex_cap = cap
-                entries.append((cap, idxs, (verts, masks)))
-                continue
-            depth = autotune.batch_bucket(b)
-            cv, cm, counts = self._static_fn(cap, target, depth)(verts, masks)
-            entries.append((target, idxs, (cv, cm)))
-            aux.append((cap, idxs, counts, verts, masks))
+        with jax.profiler.TraceAnnotation("repro.launch.pass1") as span:
+            entries, aux = [], []
+            for cap, idxs in plan.cap_groups.items():
+                b = len(idxs)
+                target = plan.static_targets[cap]
+                verts, masks = self._pad_batch(
+                    (
+                        jnp.stack([prepped[i].verts for i in idxs]),
+                        jnp.stack([prepped[i].vmask for i in idxs]),
+                    ),
+                    b,
+                )
+                if target is None:
+                    # counted parity without the bound: a floor-cap group can
+                    # never re-bucket, so its PruneInfo is metadata-only
+                    for i in idxs:
+                        n = prepped[i].n_vertices
+                        prepped[i].prune_info = prune_kernels.PruneInfo(
+                            cap, n, n, False
+                        )
+                        prepped[i].vertex_cap = cap
+                    entries.append((cap, idxs, (verts, masks)))
+                    continue
+                depth = autotune.batch_bucket(b)
+                cv, cm, counts = self._static_fn(cap, target, depth)(
+                    verts, masks)
+                entries.append((target, idxs, (cv, cm)))
+                aux.append((cap, idxs, counts, verts, masks))
+            span.set_metadata(launches=len(aux))
         return entries, aux
 
     def _resolve_static_aux(self, window, d_out):
@@ -889,7 +945,8 @@ class PlanExecutor:
                 )
                 retries.append((cap, [idxs[j] for j in retry_js], sub))
         if retries:
-            futs = self._submit(retries, self._diam_fn, self._stacked_chunk)
+            futs = self._submit("pass2b", retries, self._diam_fn,
+                                self._stacked_chunk)
             d_out.update(self._drain(futs, "pass2b_retry"))
 
     def _resolve_hint_counts(self, window, d_out):
@@ -937,7 +994,11 @@ class PlanExecutor:
         loader callable; a case that fails to load or validate is
         quarantined (NaN row) instead of killing the window.
         """
-        prepped = [self._prep_case_safe(c, fields=self.prune) for c in cases]
+        prepped = [
+            self._prep_case_safe(c, fields=self.prune,
+                                 window=self._next_window, pos=k)
+            for k, c in enumerate(cases)
+        ]
         return self.submit_prepped(prepped, batch_size)
 
     def submit_prepped(self, prepped, batch_size=None) -> _Window:
@@ -948,69 +1009,83 @@ class PlanExecutor:
         weighs the modeled sync cost of the counted schedule against the
         static schedule's padded sweeps on this window's census
         (``runtime/costmodel.CostModel.choose_schedule``).
+
+        The window takes the executor's next window number, the ``window``
+        stat of the ``repro.window.submit`` span around its planning and
+        launches and of the spans that prep and collect it.
         """
-        metas = [self._meta(p) for p in prepped]
-        schedule = self.schedule
-        if schedule == "auto":
-            schedule = self.cost_model.choose_schedule(metas)
-        plan = planlib.build_plan(metas, schedule, families=self.families)
-        family_futs = self._submit_families(plan, prepped, batch_size)
+        seq = self._next_window
+        self._next_window += 1
+        with jax.profiler.TraceAnnotation("repro.window.submit", window=seq,
+                                          cases=len(prepped)):
+            with jax.profiler.TraceAnnotation("repro.plan") as span:
+                metas = [self._meta(p) for p in prepped]
+                schedule = self.schedule
+                if schedule == "auto":
+                    schedule = self.cost_model.choose_schedule(metas)
+                plan = planlib.build_plan(metas, schedule,
+                                          families=self.families)
+                span.set_metadata(schedule=plan.schedule,
+                                  buckets=len(plan.shape_groups))
+            family_futs = self._submit_families(plan, prepped, batch_size)
 
-        mc_futs, diam_futs, fused_futs, aux = [], [], [], []
-        t_prune = 0.0
-        if not self._shape_on:
-            # intensity-only request: the family launches are the window
-            return _Window(prepped, plan, mc_futs, diam_futs, fused_futs,
-                           aux, t_prune, family_futs)
-        if not self.prune:
-            fused_entries = [
-                (bucket, idxs, self._pool(prepped, idxs))
-                for bucket, idxs in plan.fused_groups.items()
-            ]
-            fused_futs = self._submit(
-                fused_entries, self._batch_fn, self._stacked_chunk, batch_size
-            )
-            return _Window(prepped, plan, mc_futs, diam_futs, fused_futs,
-                           aux, t_prune, family_futs)
+            mc_futs, diam_futs, fused_futs, aux = [], [], [], []
+            if not self._shape_on:
+                # intensity-only request: the family launches are the window
+                return _Window(prepped, plan, mc_futs, diam_futs, fused_futs,
+                               aux, seq, family_futs)
+            if not self.prune:
+                fused_entries = (
+                    (bucket, idxs, self._pool(prepped, idxs))
+                    for bucket, idxs in plan.fused_groups.items()
+                )
+                fused_futs = self._submit(
+                    "fused", fused_entries, self._batch_fn,
+                    self._stacked_chunk, batch_size
+                )
+                return _Window(prepped, plan, mc_futs, diam_futs, fused_futs,
+                               aux, seq, family_futs)
 
-        # pass 1
-        t1 = time.perf_counter()
-        if self.device_compact:
-            if plan.schedule == "static":
-                entries, aux = self._pass1_static(plan, prepped)
+            # pass 1
+            if self.device_compact:
+                if plan.schedule == "static":
+                    entries, aux = self._pass1_static(plan, prepped)
+                else:
+                    entries, aux = self._pass1_counted(plan, prepped)
             else:
-                entries, aux = self._pass1_counted(plan, prepped)
-        else:
-            self._prune_pass(plan, prepped)
-            entries = None
-        t_prune = time.perf_counter() - t1
+                self._prune_pass(plan, prepped)
+                entries = None
 
-        # pass 2a: staged fused MC per shape bucket, straight off the pools
-        mc_entries = [
-            (shape, idxs, self._pool(prepped, idxs))
-            for shape, idxs in plan.shape_groups.items()
-        ]
-        mc_futs = self._submit(
-            mc_entries, self._mc_fn, self._stacked_chunk, batch_size
-        )
+            # pass 2a: staged fused MC per shape bucket, straight off the pools
+            mc_entries = (
+                (shape, idxs, self._pool(prepped, idxs))
+                for shape, idxs in plan.shape_groups.items()
+            )
+            mc_futs = self._submit(
+                "pass2a", mc_entries, self._mc_fn, self._stacked_chunk,
+                batch_size
+            )
 
-        # pass 2b: diameter sweep per pruned vertex bucket
-        if entries is not None:
-            diam_futs = self._submit(
-                entries, self._diam_fn, self._stacked_chunk, batch_size
-            )
-        else:
-            groups = planlib.group_indices(
-                [None if p.mask is None else len(p.verts) for p in prepped]
-            )
-            diam_futs = self._submit(
-                ((k, idxs, None) for k, idxs in groups.items()),
-                self._diam_fn,
-                self._host_chunk(lambda i: (prepped[i].verts, prepped[i].vmask)),
-                batch_size,
-            )
-        return _Window(prepped, plan, mc_futs, diam_futs, [], aux, t_prune,
-                       family_futs)
+            # pass 2b: diameter sweep per pruned vertex bucket
+            if entries is not None:
+                diam_futs = self._submit(
+                    "pass2b", entries, self._diam_fn, self._stacked_chunk,
+                    batch_size
+                )
+            else:
+                groups = planlib.group_indices(
+                    [None if p.mask is None else len(p.verts) for p in prepped]
+                )
+                diam_futs = self._submit(
+                    "pass2b",
+                    ((k, idxs, None) for k, idxs in groups.items()),
+                    self._diam_fn,
+                    self._host_chunk(
+                        lambda i: (prepped[i].verts, prepped[i].vmask)),
+                    batch_size,
+                )
+            return _Window(prepped, plan, mc_futs, diam_futs, [], aux, seq,
+                           family_futs)
 
     def resubmit_window(self, window: _Window) -> _Window:
         """Idempotently re-submit a window from its prepped device state.
@@ -1040,32 +1115,36 @@ class PlanExecutor:
         to ``max_retries`` times -- a transient device/link fault costs
         one window of recompute, not the run.  ``timeout_s`` is advisory:
         an over-deadline collect is flagged in the stats for the
-        straggler census (a blocking fetch cannot be interrupted).
+        straggler census (a blocking fetch cannot be interrupted).  The
+        drain runs inside a ``repro.window.collect`` span that carries the
+        window's number.
         """
-        policy = self.retry
-        if policy is None:
-            return self._collect_window(window)
-        attempt = 0
-        while True:
-            t0 = time.perf_counter()
-            try:
-                rows, stats = self._collect_window(window)
-            except (KeyboardInterrupt, SystemExit):
-                raise
-            except Exception:
-                if attempt >= policy.max_retries:
+        with jax.profiler.TraceAnnotation("repro.window.collect",
+                                          window=window.seq):
+            policy = self.retry
+            if policy is None:
+                return self._collect_window(window)
+            attempt = 0
+            while True:
+                t0 = time.perf_counter()
+                try:
+                    rows, stats = self._collect_window(window)
+                except (KeyboardInterrupt, SystemExit):
                     raise
-                self.window_retries += 1
-                time.sleep(policy.delay(attempt))
-                window = self.resubmit_window(window)
-                attempt += 1
-                continue
-            dt = time.perf_counter() - t0
-            if policy.timeout_s is not None and dt > policy.timeout_s:
-                stats["collect_timeout"] = dt
-            if attempt:
-                stats["window_retries"] = attempt
-            return rows, stats
+                except Exception:
+                    if attempt >= policy.max_retries:
+                        raise
+                    self.window_retries += 1
+                    time.sleep(policy.delay(attempt))
+                    window = self.resubmit_window(window)
+                    attempt += 1
+                    continue
+                dt = time.perf_counter() - t0
+                if policy.timeout_s is not None and dt > policy.timeout_s:
+                    stats["collect_timeout"] = dt
+                if attempt:
+                    stats["window_retries"] = attempt
+                return rows, stats
 
     def _collect_window(self, window: _Window):
         prepped = window.prepped
@@ -1080,12 +1159,13 @@ class PlanExecutor:
 
         if window.fused_futs:  # legacy one-pass path
             out = self._drain(window.fused_futs, "pass2")
-            rows = [
-                self._degenerate_row(p) if p.mask is None
-                else self._assemble_row(i, p, np.asarray(out[i], np.float32),
-                                        fam_out)
-                for i, p in enumerate(prepped)
-            ]
+            with jax.profiler.TraceAnnotation("repro.rows", rows=len(prepped)):
+                rows = [
+                    self._degenerate_row(p) if p.mask is None
+                    else self._assemble_row(
+                        i, p, np.asarray(out[i], np.float32), fam_out)
+                    for i, p in enumerate(prepped)
+                ]
             return rows, self._window_stats(window)
 
         shape_on = self._shape_on
@@ -1099,18 +1179,19 @@ class PlanExecutor:
             self._resolve_hint_counts(window, d_out)
 
         rows = []
-        for i, p in enumerate(prepped):
-            if p.mask is None:
-                rows.append(self._degenerate_row(p))
-                continue
-            shape_row = None
-            if shape_on:
-                shape_row = np.concatenate(
-                    [np.asarray(mc_out[i], np.float32),
-                     np.asarray(d_out[i], np.float32),
-                     np.asarray([p.n_vertices], np.float32)]
-                )
-            rows.append(self._assemble_row(i, p, shape_row, fam_out))
+        with jax.profiler.TraceAnnotation("repro.rows", rows=len(prepped)):
+            for i, p in enumerate(prepped):
+                if p.mask is None:
+                    rows.append(self._degenerate_row(p))
+                    continue
+                shape_row = None
+                if shape_on:
+                    shape_row = np.concatenate(
+                        [np.asarray(mc_out[i], np.float32),
+                         np.asarray(d_out[i], np.float32),
+                         np.asarray([p.n_vertices], np.float32)]
+                    )
+                rows.append(self._assemble_row(i, p, shape_row, fam_out))
         return rows, self._window_stats(window)
 
     def _family_row(self, family: str, payload) -> np.ndarray:
@@ -1174,7 +1255,6 @@ class PlanExecutor:
                 float(np.mean([inf.keep_fraction for inf in infos]))
                 if infos else 1.0
             ),
-            "prune_seconds": window.t_prune,
             "plan": window.plan.stats(),
         }
 

@@ -178,7 +178,7 @@ def test_stats_record_prune_trajectory():
     results, stats = BatchedExtractor(backend="ref").run(_blob_cases())
     assert stats["cases"] == 3 and stats["cases_per_second"] > 0
     assert 0.0 < stats["mean_keep_fraction"] <= 1.0
-    assert stats["prune_seconds"] >= 0.0
+    assert stats["host_fetches"].get("pass1", 0) >= 1  # pass 1 ran
     assert stats["pruned_cases"] >= 2  # 48^3 blobs must actually shrink
 
 

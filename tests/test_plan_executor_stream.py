@@ -219,10 +219,11 @@ def test_static_retry_resolves_keep_originals_exactly():
         planlib.build_plan(metas, "static"), prepped_s
     )
     assert aux, "the sphere cloud must take the static chain path"
-    futs = ex_s._submit(entries_s, ex_s._diam_fn, ex_s._stacked_chunk)
+    futs = ex_s._submit("pass2b", entries_s, ex_s._diam_fn,
+                        ex_s._stacked_chunk)
     d_s = ex_s._drain(futs, "pass2b")
     window = exmod._Window(prepped_s, planlib.build_plan(metas, "static"),
-                           [], [], [], aux, 0.0)
+                           [], [], [], aux, 0)
     ex_s._resolve_static_aux(window, d_s)
     assert ex_s.transfer_log.get("pass2b_retry", 0) >= 1  # retry really ran
     assert ex_s.transfer_log.get("pass1", 0) == 0
@@ -231,7 +232,8 @@ def test_static_retry_resolves_keep_originals_exactly():
         planlib.build_plan(metas, "counted"), prepped_c
     )
     d_c = ex_c._drain(
-        ex_c._submit(entries_c, ex_c._diam_fn, ex_c._stacked_chunk), "pass2b"
+        ex_c._submit("pass2b", entries_c, ex_c._diam_fn,
+                     ex_c._stacked_chunk), "pass2b"
     )
     for i in range(2):
         # both schedules conclude keep-originals with identical PruneInfo...
