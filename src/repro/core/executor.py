@@ -96,7 +96,7 @@ from jax.sharding import Mesh
 
 from repro.core import dispatcher
 from repro.core import plan as planlib
-from repro.core.shape_features import crop_to_roi
+from repro.core.shape_features import crop_padded, roi_box
 from repro.kernels import ops
 from repro.kernels import prune as prune_kernels
 from repro.parallel import sharding as psharding
@@ -625,11 +625,13 @@ class PlanExecutor:
         mask: an empty-mask case, which stays an all-zero feature row).
         """
         sp = np.asarray(spacing, np.float32)
-        if not np.any(mask):
+        mask = np.asarray(mask)
+        box = roi_box(mask)
+        if box is None:
             return _Prepped(spacing=sp)  # empty mask: all-zero feature row
         if self._needs_intensity:
             img = None if image is None else np.asarray(image)
-            if img is None or img.shape != np.shape(mask):
+            if img is None or img.shape != mask.shape:
                 raise ValueError(
                     "intensity families requested but the case has no "
                     "matching intensity image"
@@ -637,15 +639,16 @@ class PlanExecutor:
             if (np.issubdtype(img.dtype, np.floating)
                     and not np.isfinite(img).all()):
                 raise ValueError("non-finite intensity image (poisoned case)")
-        if image is None:  # shape-only requests never read the image
-            image = np.zeros_like(np.asarray(mask), dtype=np.float32)
-        im, m, _ = crop_to_roi(image, mask)
-        roi_shape = m.shape
-        bshape = planlib.shape_bucket(tuple(s - 2 for s in roi_shape))
-        pad = [(0, bs - ms) for bs, ms in zip(bshape, roi_shape)]
+        # the crop, the 1-voxel pad and the bucket pad in one copy per
+        # array (shape-only requests never read the image)
+        lo, hi = box
+        extent = tuple(h - l for l, h in zip(lo, hi))
+        bshape = planlib.shape_bucket(extent)
         return _Prepped(
-            mask=np.pad(m, pad), spacing=sp, shape=bshape, roi_shape=roi_shape,
-            image=np.pad(im, pad) if self._needs_intensity else None,
+            mask=crop_padded(mask, lo, hi, bshape), spacing=sp, shape=bshape,
+            roi_shape=tuple(e + 2 for e in extent),
+            image=(crop_padded(img, lo, hi, bshape)
+                   if self._needs_intensity else None),
         )
 
     def _stage_case(self, p: _Prepped, fields: bool = True,

@@ -51,6 +51,42 @@ class StageTimes:
         return self.preprocess_ms + self.transfer_ms + self.mesh_ms + self.diameter_ms
 
 
+def roi_box(mask) -> tuple[list[int], list[int]] | None:
+    """Bounding box ``(lo, hi)`` of the nonzero voxels, ``hi`` exclusive;
+    ``None`` for an empty mask.
+
+    Found from axis projections, the same box as ``np.nonzero``'s min and
+    max + 1 without its index arrays: ``np.any`` over the leading axis
+    (whole planes OR-ed together) projects the volume onto the trailing
+    axes, whose box is found the same way, and ``np.any`` over the
+    trailing axes gives the leading axis's extent.  A Fortran-ordered
+    mask is read through its C-ordered transpose, so both reductions run
+    in memory order.
+    """
+    m = np.asarray(mask)
+    if m.flags.f_contiguous and not m.flags.c_contiguous:
+        box = roi_box(m.T)
+        return None if box is None else (box[0][::-1], box[1][::-1])
+    if m.ndim == 1:
+        nz = np.flatnonzero(m)
+        return None if nz.size == 0 else ([int(nz[0])], [int(nz[-1]) + 1])
+    box = roi_box(np.any(m, axis=0))
+    if box is None:
+        return None
+    lead = np.flatnonzero(np.any(m, axis=tuple(range(1, m.ndim))))
+    return [int(lead[0])] + box[0], [int(lead[-1]) + 1] + box[1]
+
+
+def crop_padded(a, lo, hi, shape, pad: int = 1) -> np.ndarray:
+    """``a[lo:hi]`` as float32, written at offset ``pad`` into a zeroed
+    array of ``shape``: the crop, the cast and the zero pad in one copy."""
+    src = tuple(slice(l, h) for l, h in zip(lo, hi))
+    dst = tuple(slice(pad, pad + h - l) for l, h in zip(lo, hi))
+    out = np.zeros(shape, np.float32)
+    out[dst] = np.asarray(a)[src]
+    return out
+
+
 def crop_to_roi(image: np.ndarray, mask: np.ndarray, pad: int = 1):
     """Crop image/mask to the ROI bounding box and zero-pad by ``pad``.
 
@@ -59,16 +95,13 @@ def crop_to_roi(image: np.ndarray, mask: np.ndarray, pad: int = 1):
     Host-side numpy: this is part of the 'data loading' stage in the paper's
     breakdown, not the accelerated region.
     """
-    idx = np.nonzero(mask)
-    if len(idx[0]) == 0:
+    box = roi_box(mask)
+    if box is None:
         raise ValueError("mask is empty")
-    lo = [int(i.min()) for i in idx]
-    hi = [int(i.max()) + 1 for i in idx]
-    sl = tuple(slice(l, h) for l, h in zip(lo, hi))
-    m = np.ascontiguousarray(mask[sl]).astype(np.float32)
-    im = np.ascontiguousarray(image[sl]).astype(np.float32)
-    m = np.pad(m, pad)
-    im = np.pad(im, pad)
+    lo, hi = box
+    shape = tuple(h - l + 2 * pad for l, h in zip(lo, hi))
+    m = crop_padded(mask, lo, hi, shape, pad)
+    im = crop_padded(image, lo, hi, shape, pad)
     return im, m, lo
 
 
