@@ -257,7 +257,7 @@ def firstorder_packed_batch(images, masks, *, backend=None, n_bins=32,
     """Batched packed first-order stats over bucket-padded stacks.
 
     ``images``/``masks``: (B, nx, ny, nz) device stacks ->
-    (B, packed_width) stats rows ([count, sum, sum_sq, hist, lo, hi,
+    (B, packed_width) stats rows ([count, sum, sum_sq, m2, hist, lo, hi,
     bin_width]; see ``repro.kernels.firstorder``).  Designed to be
     TRACED (it runs under the executor's sharded jit), so ``block`` must
     already be concrete for kernel backends -- resolve it outside the

@@ -11,6 +11,8 @@ import, so every test worker collects the same tests and only the one
 that runs this file loads the TPU compiler.
 """
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -148,6 +150,35 @@ def test_compaction_compiles_at_largest_bucket(one_chip, block):
 def test_firstorder_compiles(one_chip, block):
     fn = functools.partial(fok.firstorder_packed_batch_pallas, block=block)
     _compile(one_chip, fn, (FAMILY_SHAPE, F32), (FAMILY_SHAPE, F32))
+
+
+def _chunk_sums(text):
+    """Operand shapes of the f32 sums over one chunk in a scan body."""
+    types = dict(re.findall(r"^\s*(?:ROOT )?%(\S+) = f32\[([\d,]*)\]\{",
+                            text, re.M))
+    shapes = [tuple(int(d) for d in types[op].split(",")) for op in
+              re.findall(r" reduce\(%([^,]+),.*/while/body/", text)
+              if op in types]
+    return [s for s in shapes if math.prod(s) == fok.CANON_CHUNK]
+
+
+@pytest.mark.parametrize("fold", ["batch", "tiled"])
+def test_firstorder_xla_folds_sum_chunks_as_the_kernel_does(one_chip, fold):
+    """Every sum over one chunk in the XLA folds reads a (1, CANON_CHUNK)
+    operand, the layout in which XLA's reduction order matches the Pallas
+    kernel's on a v5e.  A sum over a flat (CANON_CHUNK,) operand takes
+    another order there, and rounds the m2 lane apart from the kernel's
+    (``kernels/firstorder`` module docstring)."""
+    if fold == "batch":
+        fn = fok.firstorder_packed_batch_ref
+        shapes = [((4, 48, 48, 32), F32)] * 2
+    else:
+        fn = fok.fold_packed_chunks
+        shapes = [((72, fok.CANON_CHUNK), F32)] * 2 + [((), F32)] * 2
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    sums = _chunk_sums(fn.lower(*args).compile().as_text())
+    # count, sum, sum of squares, m2
+    assert sums == [(1, fok.CANON_CHUNK)] * 4, sums
 
 
 @pytest.mark.parametrize("block", at.DEFAULT_GLCM_BLOCKS)

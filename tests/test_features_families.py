@@ -284,7 +284,7 @@ def test_bin_edge_straddling_values():
     packed = np.asarray(
         fok.firstorder_packed_batch_ref(img[None], msk[None])
     )[0]
-    hist = packed[3:3 + N_BINS]
+    hist = packed[fok.N_MOMENTS:fok.N_MOMENTS + N_BINS]
     assert packed[0] == img.size
     assert hist.sum() == img.size
     np.testing.assert_array_equal(hist, np.full(N_BINS, img.size / N_BINS))
