@@ -17,8 +17,14 @@ Semantics (shared by both paths, and by the host path they replace):
     ``verts[np.nonzero(keep)]`` + zero ``np.pad``;
   * survivors beyond the cap are dropped (callers size the cap from the
     survivor count, so this only happens under a deliberately small cap);
-  * the returned count ``n`` is the TOTAL survivor count (pre-drop),
-    matching ``ref.compact_vertices``.
+  * the returned count ``n`` is the TOTAL survivor count (pre-drop).
+
+Pass 0's vertex compaction, ``ref.compact_vertices``, keeps the same
+contract over the dense edge fields (active edges for survivors): the
+first ``min(n, cap)`` slots in order, zero with a False mask after them,
+``n`` the total.  It finds its slots without a scatter over every edge:
+at 10^7 edge slots a ``mode='drop'`` scatter is slower on TPU than the
+argsort it would replace, and this kernel's grid would walk 10^5 steps.
 
 Two implementations:
 

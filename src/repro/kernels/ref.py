@@ -246,21 +246,86 @@ def mc_volume_area(vol, iso=0.5, spacing=(1.0, 1.0, 1.0), origin=(0.0, 0.0, 0.0)
 # Vertex compaction: dense per-edge fields -> padded (M,3) vertex list
 # ---------------------------------------------------------------------------
 
+_SCAN_BLOCK = 128
+
+
+def _block_scan(x, op, identity):
+    """Exact inclusive scan of a 1-D int32 array by blocks of 128.
+
+    Each block is scanned by a masked reduction over a (128, 128) lower
+    triangle, the block totals by recursion.  A ``cumsum`` or an
+    ``associative_scan`` of 10^5-10^6 elements takes the TPU compiler
+    several seconds; this takes well under one.
+    """
+    B = _SCAN_BLOCK
+    n = x.shape[0]
+    nb = -(-n // B)
+    xb = jnp.pad(x, (0, nb * B - n), constant_values=identity).reshape(nb, B)
+    lower = jnp.arange(B)[:, None] >= jnp.arange(B)[None, :]
+    reduce = jnp.sum if op is jnp.add else jnp.max
+    out = reduce(jnp.where(lower, xb[:, None, :], identity), axis=2)
+    if nb > 1:
+        before = _block_scan(out[:, -1], op, identity)
+        before = jnp.concatenate([jnp.full((1,), identity, x.dtype), before[:-1]])
+        out = op(out, before[:, None])
+    return out.reshape(-1)[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("max_vertices",))
 def compact_vertices(f: VertexFields, max_vertices: int):
     """Gather active-edge vertices into a padded (max_vertices, 3) array.
 
-    Returns (verts, mask, n_active).  Deterministic order (x-field, y-field,
-    z-field, row-major).  If there are more active vertices than
+    Returns (verts, mask, n_active).  Slot j < min(n_active, max_vertices)
+    holds the j-th active vertex in a deterministic order (x-field,
+    y-field, z-field, row-major); the slots after them are zero with a
+    False mask.  ``n_active`` is the total active count: if it exceeds
     ``max_vertices`` the excess is dropped (callers size the cap from
     ``count_vertices``).
+
+    A stable compaction without a sort.  Every row (last axis) of the
+    three active-edge fields is counted; a prefix over the row counts
+    gives each row its first output slot.  A scatter of each row's index
+    at its first slot, followed by a running max, names the row of every
+    output slot, and the slot's column is found in the in-row prefix of
+    that row, gathered and multiplied by a 0/1 triangle (exact: sums of
+    at most one row of ones).  The work is O(rows) + O(max_vertices x
+    row width), against the O(L log L) of an argsort over all L edges.
     """
+    cap = max_vertices
+    j = jnp.arange(cap, dtype=jnp.int32)
+    rows = [a.reshape(-1, a.shape[-1]) for a in (f.ax, f.ay, f.az) if a.size]
+    if not rows:
+        return jnp.zeros((cap, 3), jnp.float32), j < 0, jnp.int32(0)
+    rowc = jnp.concatenate([jnp.sum(a, axis=1, dtype=jnp.int32) for a in rows])
+    rowp = _block_scan(rowc, jnp.add, 0)
+    n = rowp[-1]
+    start = rowp - rowc  # each row's first output slot, nondecreasing
+    # a row's index and first slot at that slot: of the rows that share
+    # a first slot only the last can be non-empty, and the max keeps it
+    first = jnp.stack([jnp.arange(rowc.shape[0], dtype=jnp.int32), start], 1)
+    first = jnp.zeros((cap, 2), jnp.int32).at[start].max(
+        first, mode="drop", indices_are_sorted=True)
+    r = _block_scan(first[:, 0], jnp.maximum, 0)
+    k = j - _block_scan(first[:, 1], jnp.maximum, 0)  # rank inside row r
+    width = max(a.shape[1] for a in rows)
+    table = jnp.concatenate(
+        [jnp.pad(a, ((0, 0), (0, width - a.shape[1]))) for a in rows])
+    upper = jnp.arange(width)[:, None] <= jnp.arange(width)[None, :]
+    inrow = jnp.dot(table[r].astype(jnp.bfloat16), upper.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
+    col = jnp.sum(inrow <= k[:, None].astype(jnp.float32), axis=1,
+                  dtype=jnp.int32)
+    order = jnp.zeros_like(j)
+    row0 = slot0 = 0
+    for a in rows:  # row r, column col -> index into the concatenated fields
+        nr, w = a.shape
+        here = (r >= row0) & (r < row0 + nr)
+        order = jnp.where(here, slot0 + (r - row0) * w + col, order)
+        row0 += nr
+        slot0 += nr * w
+    mask = j < n
     pos = jnp.concatenate([f.vx.reshape(-1, 3), f.vy.reshape(-1, 3), f.vz.reshape(-1, 3)])
-    act = jnp.concatenate([f.ax.reshape(-1), f.ay.reshape(-1), f.az.reshape(-1)])
-    n = jnp.sum(act.astype(jnp.int32))
-    # stable order: active first, original order preserved among actives
-    order = jnp.argsort(~act, stable=True)[:max_vertices]
-    verts = pos[order]
-    mask = act[order]
+    verts = jnp.where(mask[:, None], pos[jnp.where(mask, order, 0)], 0.0)
     return verts, mask, n
 
 

@@ -19,13 +19,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import plan
+from repro.core import executor, plan
 from repro.data import synthetic
 from repro.kernels import compact as ck
 from repro.kernels import diameter as dk
 from repro.kernels import firstorder as fok
 from repro.kernels import glcm as gk
 from repro.kernels import marching_cubes as mck
+from repro.kernels import ops
 from repro.runtime import autotune as at
 
 # Table 2's largest ROI (236,588 vertices) buckets to M = 2^18
@@ -144,6 +145,42 @@ def test_compaction_compiles_at_largest_bucket(one_chip, block):
     M, cap = 1 << 18, 1 << 16
     fn = functools.partial(ck.compact_batch_pallas, cap=cap, block=block)
     _compile(one_chip, fn, ((4, M, 3), F32), ((4, M), jnp.bool_))
+
+
+def _compact_cap_text(one_chip, shape, cap, compact=None):
+    """HLO text of pass 0's ``_compact_cap`` compiled at a mask bucket."""
+    mask = jax.ShapeDtypeStruct(shape, F32, sharding=one_chip)
+    fields = jax.eval_shape(
+        lambda m: ops.vertex_fields(m, 0.5, (1.0, 1.0, 1.0)), mask)
+    fields = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        fields)
+    if compact is None:
+        fn = executor._compact_cap
+    else:
+        fn = jax.jit(lambda f, cap: compact(f, cap)[:2], static_argnums=1)
+    return fn.lower(fields, cap).compile().as_text()
+
+
+SORT_OP = re.compile(r"\ssort\(")  # an HLO sort instruction
+
+
+def test_sort_op_pattern_finds_an_argsort(one_chip):
+    """The pattern below sees the stable argsort pass 0 once ran."""
+    def argsort_compact(f, cap):
+        act = jnp.concatenate([a.reshape(-1) for a in (f.ax, f.ay, f.az)])
+        return act[jnp.argsort(~act, stable=True)[:cap]], act
+    text = _compact_cap_text(one_chip, (12, 10, 8), 64, argsort_compact)
+    assert SORT_OP.search(text)
+
+
+@pytest.mark.parametrize("shape", [(320, 128, 224), MC_SHAPE],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_pass0_compaction_compiles_without_a_sort(one_chip, shape):
+    """Pass 0's vertex compaction at a kidney bucket and M = 2^18 holds no
+    sort: the O(L log L) argsort over every edge slot stays gone."""
+    text = _compact_cap_text(one_chip, shape, 1 << 18)
+    assert not SORT_OP.search(text)
 
 
 @pytest.mark.parametrize("block", at.DEFAULT_FIRSTORDER_BLOCKS)
