@@ -4,12 +4,10 @@ The paper's motivating workload is ~40 000 CT scans on a cluster (xLUNGS);
 its discussion notes that for complete workflows data loading dominates
 small cases and DMA/compute overlap is the open opportunity.  This
 benchmark runs the BatchedExtractor over a batch of synthetic cases in
-eight modes -- the single-case loop, the legacy one-pass batched pipeline
-(no pruning: the unpruned baseline), the two-pass pruned pipeline with
-PR 2's host-side survivor compaction (``device_compact=False``), the
-device-resident counted pipeline (PR 3's default), the sync-free
-``schedule='static'`` pipeline (PR 4: zero pass-1 host fetches, padded
-pair-sweep work instead), the cost-model-driven auto configuration
+six modes -- the single-case loop, the device-resident counted pipeline
+(the default), the sync-free ``schedule='static'`` pipeline (zero
+pass-1 host fetches, padded pair-sweep work instead), the
+cost-model-driven auto configuration
 (PR 5: ``schedule='auto'`` + sync-free ``prep='hint'``), the streaming
 front-end (``extract_stream``, window overlap), and the fully
 self-configuring stream (``window='auto'``) -- and reports cases/second
@@ -91,27 +89,18 @@ def run(n_cases: int = 12, records=None, repeat: int = 8):
         ext.execute(img, msk, sp)
     t_loop = time.perf_counter() - t0
 
-    unpruned = BatchedExtractor(backend="ref", prune=False)
-    pruned = BatchedExtractor(backend="ref", prune=True, device_compact=False)
-    device = BatchedExtractor(backend="ref", prune=True, device_compact=True)
+    device = BatchedExtractor(backend="ref")
     static = BatchedExtractor(backend="ref", schedule="static")
     auto = BatchedExtractor(backend="ref", schedule="auto", prep="hint")
-    # the unpruned baseline is ~15x slower per run: two measured runs
-    # bound its noise well enough without dominating the bench's runtime
-    ((res_u, stats_u),) = _best_interleaved((unpruned,), cases, 2)
-    # host- vs device-compaction vs static schedule vs the cost-model-
-    # driven auto configuration are close contests: interleave their runs
-    # so machine-load drift cannot bias the winner
-    ((res_p, stats_p), (res_d, stats_d), (res_s, stats_s),
+    # the counted vs static schedule vs the cost-model-driven auto
+    # configuration are close contests: interleave their runs so
+    # machine-load drift cannot bias the winner
+    ((res_d, stats_d), (res_s, stats_s),
      (res_a, stats_a)) = _best_interleaved(
-        (pruned, device, static, auto), cases, repeat
+        (device, static, auto), cases, repeat
     )
-    assert all(r is not None for r in res_u + res_p + res_d + res_s + res_a)
-    for a, b in zip(res_u, res_p):  # pruning must not move the features
-        np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-3)
-    for a, b in zip(res_p, res_d):  # device compaction must not move a BIT
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(res_d, res_s):  # nor may the sync-free static schedule
+    assert all(r is not None for r in res_d + res_s + res_a)
+    for a, b in zip(res_d, res_s):  # the static schedule must not move a bit
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     for a, b in zip(res_d, res_a):  # nor hint prep + the auto schedule
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -266,25 +255,11 @@ def run(n_cases: int = 12, records=None, repeat: int = 8):
 
     emit("single_case_loop", t_loop)
     emit(
-        "batched_unpruned", stats_u["seconds"], stats_u,
-        buckets=stats_u["buckets"],
-        speedup_vs_loop=f"{t_loop / stats_u['seconds']:.2f}",
-    )
-    emit(
-        "batched_two_pass_pruned", stats_p["seconds"], stats_p,
-        buckets=stats_p["buckets"],
-        vertex_buckets=stats_p["vertex_buckets"],
-        keep_frac=f"{stats_p['mean_keep_fraction']:.3f}",
-        speedup_vs_loop=f"{t_loop / stats_p['seconds']:.2f}",
-        speedup_vs_unpruned=f"{stats_u['seconds'] / stats_p['seconds']:.2f}",
-    )
-    emit(
         "two_pass_device_compact", stats_d["seconds"], stats_d,
         buckets=stats_d["buckets"],
         vertex_buckets=stats_d["vertex_buckets"],
         keep_frac=f"{stats_d['mean_keep_fraction']:.3f}",
         speedup_vs_loop=f"{t_loop / stats_d['seconds']:.2f}",
-        speedup_vs_host_compact=f"{stats_p['seconds'] / stats_d['seconds']:.2f}",
     )
     emit(
         "two_pass_static", stats_s["seconds"], stats_s,

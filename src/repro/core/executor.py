@@ -9,6 +9,13 @@ pass-1 chain, the double-buffered feeds, the streaming overlap -- lives
 here, behind the thin :class:`~repro.core.pipeline.BatchedExtractor`
 facade.
 
+One data plane runs the shape passes: the two-pass pruned pipeline with
+device compaction, both passes device-resident.  Its only internal
+choice is the pass-1 schedule (``'counted'`` or ``'static'``).  The
+unpruned one-pass computation and host-side survivor compaction are not
+executor paths: they live on as test oracles (``ops.prune_candidates``
+and the per-case sweep of :meth:`PlanExecutor.extract_one`).
+
 Data plane (both passes device-resident):
 
 * **pass 0 (staging):** each case's cropped, bucket-padded mask goes to
@@ -108,8 +115,10 @@ class _Prepped:
     """Pass-0 state for one case (None mask = empty-mask case).
 
     ``mask`` is the bucket-padded mask, staged on device (the pool
-    entry); ``verts``/``vmask`` stay device-resident on the device-
-    compaction path and are host numpy on the legacy host path.
+    entry).  ``verts``/``vmask`` are the pass-0 compacted vertex stacks,
+    device arrays that pass 1 stacks without a host round trip; only the
+    single-case oracle (``extract_one``) and the hint-overflow retry put
+    the host-pruned arrays of ``ops.prune_candidates`` in their place.
     """
 
     mask: object | None = None  # device-staged bucket-padded mask
@@ -137,7 +146,6 @@ class _Window:
     plan: planlib.ExtractionPlan
     mc_futs: list
     diam_futs: list
-    fused_futs: list
     static_aux: list  # [(cap, idxs, counts_fut, verts, masks)] to resolve
     seq: int  # the executor's window number: the ``window`` stat of its spans
     family_futs: dict = dataclasses.field(default_factory=dict)
@@ -158,21 +166,6 @@ def _compact_cap(fields, cap: int):
     return verts, vmask
 
 
-def _features_one(mask, spacing, vertex_cap, backend, variant, block=None,
-                  mc_block=None, mc_chunk=None):
-    mc_kw = ({"block": mc_block, "chunk": mc_chunk} if mc_block is not None
-             else {"chunk": mc_chunk} if mc_chunk is not None else {})
-    vol, area = ops.mc_volume_area(mask, 0.5, spacing, backend=backend, **mc_kw)
-    fields = ops.vertex_fields(mask, 0.5, spacing)
-    verts, vmask, n = ops.compact_vertices(fields, vertex_cap)
-    d = ops.max_diameters(
-        verts, vmask, backend=backend, variant=variant, block=block
-    )
-    return jnp.concatenate(
-        [jnp.stack([vol, area]), d, jnp.asarray([n], jnp.float32)]
-    )  # (7,)
-
-
 class PlanExecutor:
     """Plan-driven batched extraction engine (see module docstring).
 
@@ -189,10 +182,8 @@ class PlanExecutor:
     PREPS = ("count", "hint")
 
     def __init__(self, backend=None, variant="auto", mesh: Mesh | None = None,
-                 data_axis: str = "data", prune: bool = True,
-                 mc_block="auto", mc_chunk: int | None = None,
-                 k_dirs: int = 16, device_compact: bool = True,
-                 compact_block="auto", schedule: str = "counted",
+                 data_axis: str = "data", mc_block="auto",
+                 mc_chunk: int | None = None, schedule: str = "counted",
                  prep: str = "count", cost_model=None,
                  transfer_callback=None, retry=None,
                  families=None, n_bins: int = 32):
@@ -212,30 +203,15 @@ class PlanExecutor:
                 mesh = ambient
         self.mesh = mesh
         self.data_axis = data_axis
-        self.prune = prune
         self.mc_block = mc_block
         self.mc_chunk = mc_chunk
-        self.k_dirs = k_dirs
-        self.device_compact = device_compact
-        self.compact_block = compact_block
         if schedule not in self.SCHEDULES:
             raise ValueError(
                 f"schedule must be one of {self.SCHEDULES}, got {schedule!r}"
             )
-        if schedule in ("static", "auto") and not (prune and device_compact):
-            raise ValueError(
-                f"schedule={schedule!r} is (or may resolve to) a "
-                "device-resident schedule: it requires prune=True and "
-                "device_compact=True"
-            )
         self.schedule = schedule
         if prep not in self.PREPS:
             raise ValueError(f"prep must be one of {self.PREPS}, got {prep!r}")
-        if prep == "hint" and not (prune and device_compact):
-            raise ValueError(
-                "prep='hint' is a device-resident prep: it requires "
-                "prune=True and device_compact=True"
-            )
         self.prep = prep
         self._cost_model = cost_model
         self.transfer_log = collections.Counter()
@@ -297,9 +273,7 @@ class PlanExecutor:
     def _resolve_compact(self, cap_in, depth: int = 1):
         if self.backend == "ref":
             return None
-        return dispatcher.compact_config(
-            self.backend, cap_in, self.compact_block, batch=depth
-        )
+        return dispatcher.compact_config(self.backend, cap_in, batch=depth)
 
     def _resolve_family_block(self, family: str, shape, depth: int = 1):
         """Tuned block for an intensity-family launch (None on 'ref')."""
@@ -339,10 +313,9 @@ class PlanExecutor:
         key = ("prune_bound", cap, depth)
         if key in self._compiled:
             return self._compiled[key]
-        k_dirs = self.k_dirs
 
         def batch(verts, masks):
-            keep, _ = prune_kernels.keep_mask_batch(verts, masks, k_dirs)
+            keep, _ = prune_kernels.keep_mask_batch(verts, masks)
             m_valid = jnp.sum(masks.astype(jnp.int32), axis=1)
             m_kept = jnp.sum(keep.astype(jnp.int32), axis=1)
             # counts ride out pre-stacked (B, 2) so the host fetch is one
@@ -384,11 +357,11 @@ class PlanExecutor:
         key = ("static_chain", cap, target, depth)
         if key in self._compiled:
             return self._compiled[key]
-        backend, k_dirs = self.backend, self.k_dirs
+        backend = self.backend
         block = self._resolve_compact(cap, depth)
 
         def batch(verts, masks):
-            keep, _ = prune_kernels.keep_mask_batch(verts, masks, k_dirs)
+            keep, _ = prune_kernels.keep_mask_batch(verts, masks)
             m_valid = jnp.sum(masks.astype(jnp.int32), axis=1)
             m_kept = jnp.sum(keep.astype(jnp.int32), axis=1)
             v, m, _ = ops.compact_survivors_batch(
@@ -397,27 +370,6 @@ class PlanExecutor:
             return v, m, jnp.stack([m_valid, m_kept], axis=1)
 
         fn = self._dp_map(batch, "pass1_static", check=False)
-        self._compiled[key] = fn
-        return fn
-
-    def _batch_fn(self, bucket: planlib.Bucket, depth: int):
-        """Legacy one-pass fused per-case function (``prune=False``)."""
-        key = ("one_pass", bucket, depth)
-        if key in self._compiled:
-            return self._compiled[key]
-        backend, cap = self.backend, bucket.vertex_cap
-        variant, block = self._resolve_diameter(cap, depth)
-        mc_block, mc_chunk = self._resolve_mc(bucket.shape, depth)
-
-        def one(args):
-            mask, spacing = args
-            return _features_one(mask, spacing, cap, backend, variant, block,
-                                 mc_block, mc_chunk)
-
-        def batch(masks, spacings):
-            return jax.lax.map(one, (masks, spacings))
-
-        fn = self._dp_map(batch, "fused_one_pass", check=False)
         self._compiled[key] = fn
         return fn
 
@@ -548,16 +500,6 @@ class PlanExecutor:
             )
         return sl
 
-    def _host_chunk(self, arrays_for_case):
-        """Chunk maker over host per-case arrays (the legacy pass-2b feed)."""
-
-        def make(_, s, chunk, bs):
-            filled = chunk + [chunk[0]] * (bs - len(chunk))
-            cols = zip(*(arrays_for_case(i) for i in filled))
-            return tuple(jnp.asarray(np.stack(c)) for c in cols)
-
-        return make
-
     def _pool(self, prepped, idxs):
         """Bucket-keyed device pool for one shape group: (masks, spacings).
 
@@ -611,12 +553,12 @@ class PlanExecutor:
 
     # -- pass 0: prep + device staging --------------------------------------
 
-    def _prep_case(self, image, mask, spacing, fields: bool = True,
+    def _prep_case(self, image, mask, spacing,
                    prep: str | None = None) -> _Prepped:
         """Crop, bucket-pad, device-stage, and compact one case (pass 0):
         :meth:`_crop_case` on the host, then :meth:`_stage_case`."""
         return self._stage_case(self._crop_case(image, mask, spacing),
-                                fields=fields, prep=prep)
+                                prep=prep)
 
     def _crop_case(self, image, mask, spacing) -> _Prepped:
         """Host half of pass 0: crop to the ROI and pad to its shape bucket.
@@ -651,15 +593,9 @@ class PlanExecutor:
                    if self._needs_intensity else None),
         )
 
-    def _stage_case(self, p: _Prepped, fields: bool = True,
-                    prep: str | None = None) -> _Prepped:
-        """Device half of pass 0: stage the padded volumes, then size the
-        case's vertex cap.
-
-        ``fields=False`` (the legacy one-pass path, which recomputes the
-        vertex field inside its fused kernel) skips the field/count
-        launches and sizes the cap from the metadata hint
-        (``plan.vertex_hint`` -- memoised, spacing-aware).
+    def _stage_case(self, p: _Prepped, prep: str | None = None) -> _Prepped:
+        """Device half of pass 0: stage the padded volumes, then compact
+        the case's vertex fields into its M cap on device.
 
         ``prep`` (default: the executor's configured prep) sizes the M
         cap: ``'count'`` fetches the measured dedup count (one ``int(n)``
@@ -683,12 +619,6 @@ class PlanExecutor:
             # intensity-only request: no vertex stage runs at all -- the
             # shape bucket still keys the family launches
             return p
-        if not fields:
-            p.n_vertices = planlib.vertex_hint(
-                tuple(s - 2 for s in p.roi_shape), p.spacing)
-            # pad-waste census only (the fused kernel recounts for the row)
-            p.vertex_cap = ops.vertex_bucket(p.n_vertices)
-            return p
         with jax.profiler.TraceAnnotation("repro.prep.fields") as span:
             f, n = _fields_count(p.mask, jnp.asarray(p.spacing))
             if prep == "hint":
@@ -706,17 +636,12 @@ class PlanExecutor:
                 p.n_vertices = n = int(self._fetch("prep", n))
                 cap = ops.vertex_bucket(n)
             p.verts, p.vmask = _compact_cap(f, cap)
-            if prep != "hint" and not self.device_compact:
-                # host compaction path: pull to numpy per case
-                p.verts = self._fetch("prep", p.verts)
-                p.vmask = self._fetch("prep", p.vmask)
             p.vertex_cap = cap
             span.set_metadata(cap=cap)
         return p
 
-    def _prep_case_safe(self, case, fields: bool = True,
-                        prep: str | None = None, window: int = -1,
-                        pos: int = -1) -> _Prepped:
+    def _prep_case_safe(self, case, prep: str | None = None,
+                        window: int = -1, pos: int = -1) -> _Prepped:
         """Quarantining wrapper around :meth:`_prep_case` (pass 0).
 
         ``case`` is an ``(image, mask, spacing)`` tuple or a zero-arg
@@ -752,7 +677,7 @@ class PlanExecutor:
                     p = self._crop_case(image, mask, spacing)
                     span.set_metadata(
                         voxels=0 if p.mask is None else p.mask.size)
-                return self._stage_case(p, fields=fields, prep=prep)
+                return self._stage_case(p, prep=prep)
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
@@ -777,28 +702,13 @@ class PlanExecutor:
         """Pass-0 prep of one case, quarantining any load/validation
         failure (see :meth:`_prep_case_safe`); ``case`` is an
         ``(image, mask, spacing)`` tuple or a zero-arg loader callable."""
-        return self._prep_case_safe(case, fields=self.prune)
+        return self._prep_case_safe(case)
 
     def case_meta(self, p: _Prepped) -> planlib.CaseMeta:
         """Planning metadata of a prepped case (feeds ``WindowCensus``)."""
         return self._meta(p)
 
     # -- pass 1 --------------------------------------------------------------
-
-    def _prune_pass(self, plan, prepped):
-        """Pass 1 (host path): vmapped bound + per-case host compaction."""
-        with jax.profiler.TraceAnnotation(
-                "repro.launch.pass1", launches=len(plan.cap_groups)):
-            for _, idxs in plan.cap_groups.items():
-                batch = ops.prune_candidates_batch(
-                    np.stack([prepped[i].verts for i in idxs]),
-                    np.stack([prepped[i].vmask for i in idxs]),
-                    k_dirs=self.k_dirs,
-                )
-                for i, (v2, m2, info) in zip(idxs, batch):
-                    prepped[i].verts, prepped[i].vmask = v2, m2
-                    prepped[i].vertex_cap = len(v2)
-                    prepped[i].prune_info = info
 
     def _pass1_counted(self, plan, prepped):
         """Pass 1 (counted device path): sharded bound + device compaction.
@@ -808,8 +718,9 @@ class PlanExecutor:
         buckets, and one (sharded) compaction launch per target bucket
         scatters the survivors -- the vertex data itself never leaves the
         device.  Decisions (pruned or keep-originals) come from
-        ``prune.plan_compaction``, the same rule the host path composes,
-        so the two paths stay bit-identical.  Returns the pass-2b feed:
+        ``prune.plan_compaction``, the same rule the host-compaction
+        oracle (``ops.prune_candidates``) composes, so the two stay
+        bit-identical.  Returns the pass-2b feed:
         ``[(M' bucket, case indices, (verts, vmask) stacks)]``.
         """
         with jax.profiler.TraceAnnotation("repro.launch.pass1") as span:
@@ -978,7 +889,7 @@ class PlanExecutor:
             cap = ops.vertex_bucket(n)
             f, _ = _fields_count(p.mask, jnp.asarray(p.spacing))
             verts, vmask = _compact_cap(f, cap)
-            v2, m2, info = ops.prune_candidates(verts, vmask, k_dirs=self.k_dirs)
+            v2, m2, info = ops.prune_candidates(verts, vmask)
             variant, block = self._resolve_diameter(len(v2))
             d = ops.max_diameters(
                 v2, m2, backend=self.backend, variant=variant, block=block
@@ -998,8 +909,7 @@ class PlanExecutor:
         quarantined (NaN row) instead of killing the window.
         """
         prepped = [
-            self._prep_case_safe(c, fields=self.prune,
-                                 window=self._next_window, pos=k)
+            self._prep_case_safe(c, window=self._next_window, pos=k)
             for k, c in enumerate(cases)
         ]
         return self.submit_prepped(prepped, batch_size)
@@ -1032,32 +942,15 @@ class PlanExecutor:
                                   buckets=len(plan.shape_groups))
             family_futs = self._submit_families(plan, prepped, batch_size)
 
-            mc_futs, diam_futs, fused_futs, aux = [], [], [], []
             if not self._shape_on:
                 # intensity-only request: the family launches are the window
-                return _Window(prepped, plan, mc_futs, diam_futs, fused_futs,
-                               aux, seq, family_futs)
-            if not self.prune:
-                fused_entries = (
-                    (bucket, idxs, self._pool(prepped, idxs))
-                    for bucket, idxs in plan.fused_groups.items()
-                )
-                fused_futs = self._submit(
-                    "fused", fused_entries, self._batch_fn,
-                    self._stacked_chunk, batch_size
-                )
-                return _Window(prepped, plan, mc_futs, diam_futs, fused_futs,
-                               aux, seq, family_futs)
+                return _Window(prepped, plan, [], [], [], seq, family_futs)
 
             # pass 1
-            if self.device_compact:
-                if plan.schedule == "static":
-                    entries, aux = self._pass1_static(plan, prepped)
-                else:
-                    entries, aux = self._pass1_counted(plan, prepped)
+            if plan.schedule == "static":
+                entries, aux = self._pass1_static(plan, prepped)
             else:
-                self._prune_pass(plan, prepped)
-                entries = None
+                entries, aux = self._pass1_counted(plan, prepped)
 
             # pass 2a: staged fused MC per shape bucket, straight off the pools
             mc_entries = (
@@ -1070,24 +963,11 @@ class PlanExecutor:
             )
 
             # pass 2b: diameter sweep per pruned vertex bucket
-            if entries is not None:
-                diam_futs = self._submit(
-                    "pass2b", entries, self._diam_fn, self._stacked_chunk,
-                    batch_size
-                )
-            else:
-                groups = planlib.group_indices(
-                    [None if p.mask is None else len(p.verts) for p in prepped]
-                )
-                diam_futs = self._submit(
-                    "pass2b",
-                    ((k, idxs, None) for k, idxs in groups.items()),
-                    self._diam_fn,
-                    self._host_chunk(
-                        lambda i: (prepped[i].verts, prepped[i].vmask)),
-                    batch_size,
-                )
-            return _Window(prepped, plan, mc_futs, diam_futs, [], aux, seq,
+            diam_futs = self._submit(
+                "pass2b", entries, self._diam_fn, self._stacked_chunk,
+                batch_size
+            )
+            return _Window(prepped, plan, mc_futs, diam_futs, aux, seq,
                            family_futs)
 
     def resubmit_window(self, window: _Window) -> _Window:
@@ -1159,17 +1039,6 @@ class PlanExecutor:
             family: self._drain(futs, family)
             for family, futs in window.family_futs.items()
         }
-
-        if window.fused_futs:  # legacy one-pass path
-            out = self._drain(window.fused_futs, "pass2")
-            with jax.profiler.TraceAnnotation("repro.rows", rows=len(prepped)):
-                rows = [
-                    self._degenerate_row(p) if p.mask is None
-                    else self._assemble_row(
-                        i, p, np.asarray(out[i], np.float32), fam_out)
-                    for i, p in enumerate(prepped)
-                ]
-            return rows, self._window_stats(window)
 
         shape_on = self._shape_on
         mc_out = self._drain(window.mc_futs, "pass2a")
@@ -1281,8 +1150,6 @@ class PlanExecutor:
             seconds=dt,
             cases_per_second=window.plan.n_cases / dt if dt > 0 else float("inf"),
             data_parallel=psharding.axis_size(self.mesh, self.data_axis),
-            two_pass=self.prune,
-            device_compact=self.prune and self.device_compact,
             schedule=self.schedule,  # 'auto' here; plan.schedule = resolved
             prep=self.prep,
             host_fetches={
@@ -1353,7 +1220,7 @@ class PlanExecutor:
         buf: list = []
         census = planlib.WindowCensus()
         for case in cases:
-            p = self._prep_case_safe(case, fields=self.prune)
+            p = self._prep_case_safe(case)
             meta = self._meta(p)
             if buf and cm.should_close(census, meta):
                 state = self.submit_prepped(buf, batch_size)
@@ -1383,7 +1250,8 @@ class PlanExecutor:
         """Single-case pruned path: the batched pipeline's parity oracle.
 
         Runs the identical stages (same bucket padding, pruning, tuned
-        configs, kernels) without any batching; returns a
+        configs, kernels) without any batching, with pass 1 as the
+        host-compaction oracle (``ops.prune_candidates``); returns a
         ``(row_width(families),)`` row -- (7,) for the default shape-only
         request.  Intensity families run at batch depth 1 through the
         same ``ops`` entry points as the batched pipeline (canonical-chunk
@@ -1397,10 +1265,8 @@ class PlanExecutor:
         parts = []
         for family in self.families:
             if family == "shape":
-                if self.prune:
-                    p.verts, p.vmask, p.prune_info = ops.prune_candidates(
-                        p.verts, p.vmask, k_dirs=self.k_dirs
-                    )
+                p.verts, p.vmask, p.prune_info = ops.prune_candidates(
+                    p.verts, p.vmask)
                 mc_block, mc_chunk = self._resolve_mc(p.shape)
                 mc_kw = ({"block": mc_block, "chunk": mc_chunk}
                          if mc_block is not None

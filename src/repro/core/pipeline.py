@@ -94,10 +94,12 @@ of the requested set (``plan.family_slices`` / ``plan.feature_names``);
 batched, streamed, and single-case extraction stay bit-identical per
 family.  Quarantined cases degrade to full-width NaN rows.
 
-Legacy paths kept as parity baselines: ``prune=False`` (one-pass fused
-pipeline), ``device_compact=False`` (PR 2 host-side compaction).
-Empty-mask cases yield all-zero rows instead of raising: a 40k-case
-sweep must not die on one degenerate segmentation.
+The shape passes run one data plane: the two-pass pruned pipeline with
+device compaction.  The unpruned one-pass computation and host-side
+survivor compaction (``ops.prune_candidates``, which ``extract_one``
+runs) are test oracles, not options.  Empty-mask cases yield all-zero
+rows instead of raising: a 40k-case sweep must not die on one
+degenerate segmentation.
 
 Resilience (``runtime/resilience``) -- the layer that makes the 40k-case
 cluster run *survivable*, not just fast:
@@ -200,18 +202,15 @@ class BatchedExtractor:
     """Vectorised multi-case extraction, optionally sharded over a mesh.
 
     The public facade over ``plan.build_plan`` + ``executor.PlanExecutor``
-    (see the module docstring for the architecture).  ``prune=True``
-    (default) runs the two-pass pruned pipeline; ``prune=False`` the
-    legacy one-pass path.  ``device_compact=True`` (default) keeps pass
-    1's survivor compaction on device; ``device_compact=False`` selects
-    the PR 2 host-side compaction -- bit-identical features, kept as the
-    parity baseline.  ``schedule='static'`` removes the pass-1 count
-    sync (bit-identical to ``'counted'``, tier-1-locked);
-    ``schedule='auto'`` lets the cost model pick per window.
+    (see the module docstring for the architecture): the two-pass
+    pruned pipeline, with pass 1's survivor compaction on device.
+    ``schedule='static'`` removes the pass-1 count sync (bit-identical
+    to ``'counted'``, tier-1-locked); ``schedule='auto'`` lets the cost
+    model pick per window.
     ``prep='hint'`` removes the last per-case pass-0 sync (hint-sized
     caps, overflow retried at collect; bit-identical to ``'count'``).
-    ``variant='auto'`` / ``mc_block='auto'`` / ``compact_block='auto'``
-    resolve the measured-best kernel configurations per (bucket,
+    ``variant='auto'`` / ``mc_block='auto'`` (and the compaction block,
+    always) resolve the measured-best kernel configurations per (bucket,
     batch-depth) from the autotune cache.  ``mesh`` defaults to the
     ambient ``parallel.sharding.use_mesh`` context.  ``retry`` takes a
     ``runtime/resilience.RetryPolicy`` for backed-off per-window retry;
@@ -226,20 +225,17 @@ class BatchedExtractor:
     N_FEATURES = PlanExecutor.N_FEATURES
 
     def __init__(self, backend=None, variant="auto", mesh: Mesh | None = None,
-                 data_axis: str = "data", prune: bool = True,
-                 mc_block="auto", mc_chunk: int | None = None,
-                 k_dirs: int = 16, device_compact: bool = True,
-                 compact_block="auto", schedule: str = "counted",
+                 data_axis: str = "data", mc_block="auto",
+                 mc_chunk: int | None = None, schedule: str = "counted",
                  prep: str = "count", transfer_callback=None, retry=None,
                  families=None, n_bins: int = 32, tiled: bool = False,
                  tile_prune: str = "bounds",
                  tile_mem_mb: float | None = None):
         self.executor = PlanExecutor(
             backend=backend, variant=variant, mesh=mesh, data_axis=data_axis,
-            prune=prune, mc_block=mc_block, mc_chunk=mc_chunk, k_dirs=k_dirs,
-            device_compact=device_compact, compact_block=compact_block,
-            schedule=schedule, prep=prep, transfer_callback=transfer_callback,
-            retry=retry, families=families, n_bins=n_bins,
+            mc_block=mc_block, mc_chunk=mc_chunk, schedule=schedule,
+            prep=prep, transfer_callback=transfer_callback, retry=retry,
+            families=families, n_bins=n_bins,
         )
         ex = self.executor
         self.tiled = bool(tiled)
@@ -254,8 +250,6 @@ class BatchedExtractor:
         self.variant = ex.variant
         self.mesh = ex.mesh
         self.data_axis = ex.data_axis
-        self.prune = ex.prune
-        self.device_compact = ex.device_compact
         self.schedule = ex.schedule
         self.prep = ex.prep
 

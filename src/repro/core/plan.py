@@ -346,14 +346,6 @@ class ExtractionPlan:
     def n_cases(self) -> int:
         return len(self.metas)
 
-    @property
-    def fused_groups(self) -> dict:
-        """(shape, cap) ``Bucket`` grouping for the legacy one-pass path."""
-        return group_indices(
-            [None if m.empty else Bucket(m.shape, m.vertex_cap)
-             for m in self.metas]
-        )
-
     def work_census(self) -> tuple:
         """Every kernel launch this plan implies, as :class:`WorkItem` rows.
 

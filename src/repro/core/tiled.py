@@ -136,7 +136,7 @@ class TiledExtractor:
         if need_wit:
             from repro.kernels import prune as _prune
 
-            dirs = _prune._directions((0, 1, 2), self.ex.k_dirs)  # (K, 3)
+            dirs = _prune._directions((0, 1, 2), _prune.K_DIRS)  # (K, 3)
             pmax = np.full(len(dirs), -np.inf)
             pmin = np.full(len(dirs), np.inf)
             wmax = np.zeros((len(dirs), 3), np.int64)
@@ -580,9 +580,7 @@ class TiledExtractor:
         verts[:n_emitted] = verts_sorted
         vmask = np.zeros(cap, bool)
         vmask[:n_emitted] = True
-        if ex.prune:
-            verts, vmask, _ = ops.prune_candidates(verts, vmask,
-                                                   k_dirs=ex.k_dirs)
+        verts, vmask, _ = ops.prune_candidates(verts, vmask)
         variant, block = ex._resolve_diameter(len(verts))
         d = ops.max_diameters(verts, vmask, backend=ex.backend,
                               variant=variant, block=block)

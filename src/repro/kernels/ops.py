@@ -15,6 +15,7 @@ import numpy as np
 from repro.core import dispatcher
 from repro.kernels import diameter as _diam
 from repro.kernels import marching_cubes as _mc
+from repro.kernels import prune as _prune
 from repro.kernels import ref as _ref
 
 
@@ -188,7 +189,7 @@ def _rebucket_pruned(orig_verts, orig_mask, v2, m2, info):
     return v2, m2, info
 
 
-def prune_candidates(verts, mask, k_dirs: int = 16):
+def prune_candidates(verts, mask, k_dirs: int = _prune.K_DIRS):
     """Exact host-side candidate pruning + re-bucketing for the pair sweep.
 
     Shrinks the vertex list to the provably-sufficient candidate set
@@ -198,8 +199,6 @@ def prune_candidates(verts, mask, k_dirs: int = 16):
     ``(verts', mask', info)``; on degenerate inputs the originals come
     back unchanged.
     """
-    from repro.kernels import prune as _prune
-
     v2, m2, info = _prune.prune_vertices(verts, mask, k_dirs=k_dirs)
     return _rebucket_pruned(verts, mask, v2, m2, info)
 
@@ -228,19 +227,17 @@ def compact_survivors_batch(verts, keep, cap: int, *, backend=None,
     )
 
 
-def prune_candidates_batch(verts, masks, k_dirs: int = 16):
+def prune_candidates_batch(verts, masks, k_dirs: int = _prune.K_DIRS):
     """Batched :func:`prune_candidates` for a (B, M, 3) stack of cases.
 
     The keep-mask bound runs as ONE vmapped kernel over the whole stack
     (the two-pass pipeline's pass 1); compaction + re-bucketing are per
     case HOST-side because the pruned counts M' are ragged.  Returns a
-    list of B ``(verts', mask', info)`` triples.  This is the
-    ``device_compact=False`` path of the batched pipeline; the default
-    device-resident path pairs :func:`repro.kernels.prune.keep_mask_batch`
-    with :func:`compact_survivors_batch` instead.
+    list of B ``(verts', mask', info)`` triples.  This is the host-
+    compaction oracle the batched pipeline is tested against; the
+    pipeline itself pairs :func:`repro.kernels.prune.keep_mask_batch`
+    with :func:`compact_survivors_batch` on device.
     """
-    from repro.kernels import prune as _prune
-
     verts_np = np.asarray(verts, np.float32)
     masks_np = np.asarray(masks)
     return [

@@ -51,6 +51,10 @@ import numpy as np
 
 COMBOS = ((0, 1, 2), (0, 1), (0, 2), (1, 2))  # 3D, xy, xz, yz
 
+# sampled projection directions per combo for the lower bound (every
+# caller -- pipeline, drop-in, tiled census -- uses this one value)
+K_DIRS = 16
+
 # relative slack on the squared upper bound; >> f32 rounding, prunes
 # a negligible shell of borderline candidates less aggressively
 _SLACK = np.float32(1.0 + 1e-4)
@@ -97,7 +101,7 @@ def _directions(combo: tuple, k: int) -> np.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("k_dirs",))
-def candidate_keep_mask(verts, mask, k_dirs: int = 16):
+def candidate_keep_mask(verts, mask, k_dirs: int = K_DIRS):
     """Exact per-vertex keep mask for the 4-combo diameter search.
 
     Returns ``(keep, lower_sq)``: ``keep`` is a (M,) bool mask (False =
@@ -181,7 +185,7 @@ def _compact_survivors(verts_np, mask_np, keep):
     )
 
 
-def prune_vertices(verts, mask, k_dirs: int = 16):
+def prune_vertices(verts, mask, k_dirs: int = K_DIRS):
     """Host-side pruning: compact survivors into a dense candidate list.
 
     Returns ``(verts', mask', info)`` as numpy arrays with
@@ -199,7 +203,7 @@ def prune_vertices(verts, mask, k_dirs: int = 16):
 
 
 @functools.partial(jax.jit, static_argnames=("k_dirs",))
-def keep_mask_batch(verts, masks, k_dirs: int = 16):
+def keep_mask_batch(verts, masks, k_dirs: int = K_DIRS):
     """Vmapped :func:`candidate_keep_mask` over a (B, M, 3) stack.
 
     The two-pass pipeline's pass-1 bound: ONE launch computes every case's
@@ -234,7 +238,7 @@ def plan_compaction(m_total: int, m_valid: int, m_kept: int, bucket_fn):
     return cap, PruneInfo(m_total, m_valid, m_kept, True)
 
 
-def prune_vertices_batch(verts, masks, k_dirs: int = 16):
+def prune_vertices_batch(verts, masks, k_dirs: int = K_DIRS):
     """Batched pass-1 pruning bound for a stack of same-cap cases.
 
     ``verts``: (B, M, 3), ``masks``: (B, M).  One vmapped keep-mask kernel

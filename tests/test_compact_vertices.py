@@ -140,11 +140,13 @@ def _studies():
     return [make_case(s, seed=100 + i) for i, s in enumerate(STUDIES)]
 
 
-def _rows_with(compact, monkeypatch, **kw):
-    """Stream the studies with ``compact`` as pass 0's compaction.
+def _rows_with(compact, monkeypatch, single=False, **kw):
+    """Stream the studies with ``compact`` as pass 0's compaction
+    (``single``: extract them one by one through the host-compaction
+    oracle, ``extract_one``, instead).
 
-    Every compiled program is dropped first: ``_compact_cap`` and the
-    one-pass path look ``ops.compact_vertices`` up when they trace.
+    Every compiled program is dropped first: ``_compact_cap`` looks
+    ``ops.compact_vertices`` up when it traces.
     """
     calls = []
 
@@ -156,7 +158,9 @@ def _rows_with(compact, monkeypatch, **kw):
     jax.clear_caches()
     bx = BatchedExtractor(backend="ref", families=("shape", "firstorder",
                                                    "glcm"), **kw)
-    rows = [np.asarray(r) for r in bx.extract_stream(_studies(), window=4)]
+    rows = [np.asarray(r) for r in (
+        [bx.extract_one(*c) for c in _studies()] if single
+        else bx.extract_stream(_studies(), window=4))]
     monkeypatch.undo()
     jax.clear_caches()
     assert calls, "the compaction under test never ran"
@@ -166,7 +170,7 @@ def _rows_with(compact, monkeypatch, **kw):
 @pytest.mark.parametrize("kw", [
     {"prep": "count"},
     {"prep": "hint"},
-    {"prep": "count", "device_compact": False},
+    {"prep": "count", "single": True},
 ], ids=["count", "hint-overflow", "host-compact"])
 def test_stream_rows_bit_identical_to_argsort(monkeypatch, kw):
     if kw["prep"] == "hint":

@@ -128,11 +128,7 @@ def test_hint_overflow_retries_count_sized(monkeypatch, schedule):
         assert ex.transfer_log.get("pass1", 0) == 0  # still sync-free
 
 
-def test_hint_prep_requires_device_resident_path():
-    with pytest.raises(ValueError, match="device-resident"):
-        BatchedExtractor(backend="ref", prep="hint", prune=False)
-    with pytest.raises(ValueError, match="device-resident"):
-        BatchedExtractor(backend="ref", prep="hint", device_compact=False)
+def test_unknown_prep_is_refused():
     with pytest.raises(ValueError, match="prep"):
         BatchedExtractor(backend="ref", prep="guess")
 
@@ -224,13 +220,6 @@ def test_schedule_auto_forced_static_by_spied_sync_entry():
     assert stats["plan"]["schedule"] == "static"
     assert bx.executor.transfer_log.get("pass1", 0) == 0  # it really was
     _assert_rows_equal(want, rows)
-
-
-def test_schedule_auto_requires_device_resident_path():
-    with pytest.raises(ValueError, match="device-resident"):
-        BatchedExtractor(backend="ref", schedule="auto", prune=False)
-    with pytest.raises(ValueError, match="device-resident"):
-        BatchedExtractor(backend="ref", schedule="auto", device_compact=False)
 
 
 def test_choose_schedule_census_sensitivity():

@@ -1,7 +1,7 @@
 """The executor's profiler spans and program names.
 
-Two windows of a three-family stream, a static-schedule window and a
-one-pass window run under ``jax.profiler.trace``; the trace is read back
+Two windows of a three-family stream and a static-schedule window run
+under ``jax.profiler.trace``; the trace is read back
 with ``ProfileData``.  The spans carry the counts the benchmark reads
 (``chipbench/spans.py``), so what they say is checked against what the
 program did: its ``transfer_log``, the arrays it staged, its windows and
@@ -26,11 +26,11 @@ SPANS = {
     "repro.window.submit", "repro.prep", "repro.prep.crop",
     "repro.prep.stage", "repro.prep.fields", "repro.plan",
     "repro.launch.firstorder", "repro.launch.glcm", "repro.launch.pass1",
-    "repro.launch.pass2a", "repro.launch.pass2b", "repro.launch.fused",
+    "repro.launch.pass2a", "repro.launch.pass2b",
     "repro.fetch", "repro.window.collect", "repro.rows",
 }
 PROGRAMS = {
-    "pass1_bound", "pass1_compact", "pass1_static", "fused_one_pass",
+    "pass1_bound", "pass1_compact", "pass1_static",
     "pass2a_mc", "family_firstorder", "family_glcm", "pass2b_diameter",
 }
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -63,14 +63,12 @@ def traced(tmp_path_factory):
         mp.setenv("REPRO_AUTOTUNE_CACHE", str(out / "autotune.json"))
         stream = BatchedExtractor(backend="ref", families=FAMILIES)
         static = BatchedExtractor(backend="ref", schedule="static")
-        fused = BatchedExtractor(backend="ref", prune=False)
         jax.clear_caches()  # every program of the runs below compiles
         jax.monitoring.register_event_duration_secs_listener(on_event)
         try:
             with jax.profiler.trace(str(out)):
                 rows = list(stream.extract_stream(_cases(), window=2))
                 static.run(_cases()[::2])
-                fused.run(_cases()[1:2])
         finally:
             live[0] = False
     path = sorted(glob.glob(str(out / "plugins" / "profile" / "*" /
@@ -87,7 +85,7 @@ def traced(tmp_path_factory):
                                   int(ev.end_ns), ev.name, dict(ev.stats)))
     spans.sort(key=lambda s: (s[1], -s[2]))
     return {"spans": spans, "rows": rows, "compiled": compiled,
-            "executors": [stream.executor, static.executor, fused.executor]}
+            "executors": [stream.executor, static.executor]}
 
 
 def _named(traced, name):
@@ -101,7 +99,7 @@ def test_every_span_appears(traced):
 
 def test_prep_children_nest_inside_prep(traced):
     preps = _named(traced, "repro.prep")
-    assert len(preps) == 4 + 2 + 1
+    assert len(preps) == 4 + 2
     for child in ("repro.prep.crop", "repro.prep.stage", "repro.prep.fields"):
         found = _named(traced, child)
         assert found
@@ -137,23 +135,23 @@ def test_submit_and_collect_share_window_ids(traced):
         return [s[4]["window"] for s in _named(traced, name)]
 
     submits, collects = ids("repro.window.submit"), ids("repro.window.collect")
-    # the stream's two windows, then one window of each other executor
-    assert submits == collects == [0, 1, 0, 0]
+    # the stream's two windows, then the static executor's one window
+    assert submits == collects == [0, 1, 0]
     stream_preps = _named(traced, "repro.prep")[:4]
     assert [(s[4]["window"], s[4]["case"]) for s in stream_preps] == [
         (0, 0), (0, 1), (1, 0), (1, 1)]
     plans = _named(traced, "repro.plan")
     assert [s[4]["schedule"] for s in plans] == [
-        "counted", "counted", "static", "counted"]
+        "counted", "counted", "static"]
     assert [s[4]["buckets"] for s in plans[:2]] == [1, 2]
     rows = _named(traced, "repro.rows")
-    assert [s[4]["rows"] for s in rows] == [2, 2, 2, 1]
+    assert [s[4]["rows"] for s in rows] == [2, 2, 2]
 
 
 def test_launch_spans_count_dispatches(traced):
     for name in ("repro.launch.pass1", "repro.launch.pass2a",
                  "repro.launch.pass2b", "repro.launch.firstorder",
-                 "repro.launch.glcm", "repro.launch.fused"):
+                 "repro.launch.glcm"):
         assert all(s[4]["launches"] >= 1 for s in _named(traced, name)), name
 
 

@@ -1,13 +1,14 @@
 """Device-resident pass-1 compaction: device == host bit-identity lockdown.
 
 The contract under test (see core/pipeline.py and kernels/compact.py): the
-device-compaction pipeline (``device_compact=True``, the default) must be
-**bit-identical** to the PR 2 host-compaction path (``device_compact=False``)
--- same survivors, same stable order, same zero padding, same features --
-on every edge the host path handles: empty masks, zero-survivor keeps,
-all-survivor keeps, exact cap-boundary counts, and case permutations.
-Kernel-level parity (Pallas interpret == jnp ref == host numpy) is asserted
-directly; pipeline-level parity runs the full two-pass extractor both ways.
+batched pipeline's device compaction must be **bit-identical** to host
+compaction -- same survivors, same stable order, same zero padding, same
+features -- on every edge the host path handles: empty masks,
+zero-survivor keeps, all-survivor keeps, exact cap-boundary counts, and
+case permutations.  Kernel-level parity (Pallas interpret == jnp ref ==
+host numpy) is asserted directly; pipeline-level parity compares the
+batched rows with the host-compaction oracle: per case,
+``ops.prune_candidates`` and the pair sweep (``extract_one``).
 Seeded plain-pytest mirrors of the hypothesis compaction invariants
 (tests/test_prune_properties.py) ride along for the minimal container.
 """
@@ -166,7 +167,7 @@ def test_plan_matches_host_path_info(seed):
 
 
 # ---------------------------------------------------------------------------
-# pipeline-level parity: device_compact=True == device_compact=False
+# pipeline-level parity: batched device compaction == host-compaction oracle
 # ---------------------------------------------------------------------------
 
 
@@ -185,45 +186,68 @@ def _edge_cases():
     ]
 
 
+def _host_oracle(bx, cases):
+    """Host compaction per case: the rows and the window stats it implies.
+
+    Each non-empty case is prepped count-sized and pruned on the host by
+    ``ops.prune_candidates``; its ``PruneInfo`` and pruned vertex bucket
+    give the stats the batched window must report, and ``extract_one``
+    (the same host pruning, then the pair sweep) gives its row.
+    """
+    infos, caps = [], set()
+    for case in cases:
+        p = bx.executor._prep_case(*case, prep="count")
+        if p.mask is None:
+            continue
+        v2, _, info = ops.prune_candidates(p.verts, p.vmask)
+        infos.append(info)
+        caps.add(len(v2))
+    stats = {
+        "pruned_cases": sum(1 for inf in infos if inf.pruned),
+        "vertex_buckets": len(caps),
+        "mean_keep_fraction": float(
+            np.mean([inf.keep_fraction for inf in infos])),
+    }
+    return [bx.extract_one(*c) for c in cases], stats
+
+
+def _assert_matches_host_oracle(bx, cases):
+    rows, stats = bx.run(cases)
+    want_rows, want = _host_oracle(bx, cases)
+    for key, value in want.items():
+        assert stats[key] == value, key
+    for i, (a, b) in enumerate(zip(rows, want_rows)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"case {i}")
+    return stats
+
+
 def test_device_compact_is_the_default():
-    bx = BatchedExtractor(backend="ref")
-    assert bx.device_compact
-    _, stats = bx.run([_case((20, 18, 16), 5)])
-    assert stats["device_compact"] and stats["two_pass"]
-    _, stats = BatchedExtractor(backend="ref", device_compact=False).run(
-        [_case((20, 18, 16), 5)]
-    )
-    assert not stats["device_compact"]
+    """Device compaction is the only path: the vertex data never leaves
+    the device, so pass 0 fetches one count per non-empty case and
+    nothing else, and the removed host-path options are refused."""
+    cases = _edge_cases()
+    _, stats = BatchedExtractor(backend="ref").run(cases)
+    assert stats["empty_cases"] == 1
+    assert stats["host_fetches"]["prep"] == len(cases) - 1
+    for option in ("prune", "device_compact", "k_dirs", "compact_block"):
+        with pytest.raises(TypeError, match=option):
+            BatchedExtractor(backend="ref", **{option: None})
 
 
 def test_device_vs_host_bit_identical_ref():
-    cases = _edge_cases()
-    dev = BatchedExtractor(backend="ref", device_compact=True)
-    host = BatchedExtractor(backend="ref", device_compact=False)
-    rd, sd = dev.run(cases)
-    rh, sh = host.run(cases)
-    for key in ("pruned_cases", "empty_cases", "vertex_buckets", "buckets",
-                "mean_keep_fraction"):
-        assert sd[key] == sh[key], key
-    for i, (a, b) in enumerate(zip(rd, rh)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=f"case {i}")
+    stats = _assert_matches_host_oracle(BatchedExtractor(backend="ref"),
+                                        _edge_cases())
+    assert stats["empty_cases"] == 1 and stats["buckets"] == 2
 
 
 def test_device_vs_host_bit_identical_interpret():
     """Pallas semantics: the compaction kernel itself runs (interpret) and
-    the features must still match the host path bit-for-bit."""
+    the features must still match host compaction bit-for-bit."""
     cases = [_case((48, 48, 48), 2), _case((20, 18, 16), 5)]
-    dev = BatchedExtractor(backend="interpret", device_compact=True)
-    host = BatchedExtractor(backend="interpret", device_compact=False)
-    rd, sd = dev.run(cases)
-    rh, _ = host.run(cases)
-    assert sd["pruned_cases"] >= 1  # the compaction kernel actually ran
-    for a, b in zip(rd, rh):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # extract_one stays the single-case parity oracle of the device path
-    np.testing.assert_array_equal(
-        np.asarray(rd[0]), dev.extract_one(*cases[0])
-    )
+    stats = _assert_matches_host_oracle(
+        BatchedExtractor(backend="interpret"), cases)
+    assert stats["pruned_cases"] >= 1  # the compaction kernel actually ran
 
 
 def test_device_permutation_invariance():

@@ -55,7 +55,7 @@ def test_two_pass_bit_identical_to_single_interpret():
     bx = BatchedExtractor(backend="interpret")
     cases = _blob_cases()
     results, stats = bx.run(cases)
-    assert stats["two_pass"] and stats["pruned_cases"] >= 2
+    assert stats["pruned_cases"] >= 2
     assert stats["buckets"] >= 2  # the elongated case straddles shapes
     for case, row in zip(cases, results):
         single = bx.extract_one(*case)
@@ -104,16 +104,15 @@ def test_empty_mask_yields_zero_row_not_crash():
     img = np.zeros((12, 12, 12), np.float32)
     empty = (img, np.zeros((12, 12, 12), np.float32), (1.0, 1.0, 1.0))
     good = _case((20, 18, 16), 5)
-    for prune_flag in (True, False):
-        bx = BatchedExtractor(backend="ref", prune=prune_flag)
-        results, stats = bx.run([empty, good, empty])
-        assert stats["empty_cases"] == 2
-        np.testing.assert_array_equal(results[0], np.zeros(7, np.float32))
-        np.testing.assert_array_equal(results[2], np.zeros(7, np.float32))
-        assert np.all(np.isfinite(results[1])) and results[1][0] > 0
-        np.testing.assert_array_equal(
-            bx.extract_one(*empty), np.zeros(7, np.float32)
-        )
+    bx = BatchedExtractor(backend="ref")
+    results, stats = bx.run([empty, good, empty])
+    assert stats["empty_cases"] == 2
+    np.testing.assert_array_equal(results[0], np.zeros(7, np.float32))
+    np.testing.assert_array_equal(results[2], np.zeros(7, np.float32))
+    assert np.all(np.isfinite(results[1])) and results[1][0] > 0
+    np.testing.assert_array_equal(
+        bx.extract_one(*empty), np.zeros(7, np.float32)
+    )
     # the strict single-case extractor keeps its documented ValueError
     with pytest.raises(ValueError, match="empty"):
         ShapeFeatureExtractor(backend="ref").execute(empty[0], empty[1])
@@ -164,14 +163,28 @@ def test_permutation_invariance_of_outputs():
         np.testing.assert_array_equal(permuted[j], base[i])
 
 
+def _features_one(mask, spacing, vertex_cap, backend):
+    """The unpruned one-pass row of one bucket-padded case: MC, then the
+    pair sweep over every compacted vertex (no pruning bound)."""
+    vol, area = ops.mc_volume_area(mask, 0.5, spacing, backend=backend)
+    fields = ops.vertex_fields(mask, 0.5, spacing)
+    verts, vmask, n = ops.compact_vertices(fields, vertex_cap)
+    d = ops.max_diameters(verts, vmask, backend=backend)
+    return np.concatenate([np.asarray([vol, area], np.float32),
+                           np.asarray(d, np.float32),
+                           np.asarray([n], np.float32)])
+
+
 def test_one_pass_two_pass_agree():
-    """The legacy unpruned pipeline stays a valid baseline."""
+    """The unpruned one-pass computation stays a valid oracle."""
     cases = _blob_cases()[:2]
-    two, _ = BatchedExtractor(backend="ref", prune=True).run(cases)
-    one, stats = BatchedExtractor(backend="ref", prune=False).run(cases)
-    assert not stats["two_pass"] and stats["pruned_cases"] == 0
-    for a, b in zip(two, one):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+    bx = BatchedExtractor(backend="ref")
+    two, stats = bx.run(cases)
+    assert stats["pruned_cases"] >= 1  # the bound really shrank a case
+    for case, row in zip(cases, two):
+        p = bx.executor._prep_case(*case)
+        one = _features_one(p.mask, p.spacing, p.vertex_cap, "ref")
+        np.testing.assert_allclose(row, one, rtol=1e-5, atol=1e-5)
 
 
 def test_stats_record_prune_trajectory():

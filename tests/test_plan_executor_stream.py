@@ -136,12 +136,7 @@ def test_build_plan_grouping_partition_and_stats():
     assert mplan.n_cases == 2 and mplan.stats()["shape_buckets"] >= 1
 
 
-def test_static_schedule_requires_device_resident_path():
-    with pytest.raises(ValueError, match="device-resident"):
-        BatchedExtractor(backend="ref", schedule="static", prune=False)
-    with pytest.raises(ValueError, match="device-resident"):
-        BatchedExtractor(backend="ref", schedule="static",
-                         device_compact=False)
+def test_unknown_schedule_is_refused():
     with pytest.raises(ValueError, match="schedule"):
         BatchedExtractor(backend="ref", schedule="eager")
 
@@ -223,7 +218,7 @@ def test_static_retry_resolves_keep_originals_exactly():
                         ex_s._stacked_chunk)
     d_s = ex_s._drain(futs, "pass2b")
     window = exmod._Window(prepped_s, planlib.build_plan(metas, "static"),
-                           [], [], [], aux, 0)
+                           [], [], aux, 0)
     ex_s._resolve_static_aux(window, d_s)
     assert ex_s.transfer_log.get("pass2b_retry", 0) >= 1  # retry really ran
     assert ex_s.transfer_log.get("pass1", 0) == 0
