@@ -218,7 +218,11 @@ def test_firstorder_xla_folds_sum_chunks_as_the_kernel_does(one_chip, fold):
     assert sums == [(1, fok.CANON_CHUNK)] * 4, sums
 
 
-@pytest.mark.parametrize("block", at.DEFAULT_GLCM_BLOCKS)
-def test_glcm_compiles(one_chip, block):
-    fn = functools.partial(gk.glcm_matrix_batch_pallas, block=block)
+@pytest.mark.parametrize("block,n_bins", [
+    *(pytest.param(b, gk.N_BINS, id=str(b)) for b in at.DEFAULT_GLCM_BLOCKS),
+    pytest.param(at.DEFAULT_GLCM_CONFIG.block, 64, id="default-bins64"),
+])
+def test_glcm_compiles(one_chip, block, n_bins):
+    fn = functools.partial(gk.glcm_matrix_batch_pallas, block=block,
+                           n_bins=n_bins)
     _compile(one_chip, fn, (FAMILY_SHAPE, F32), (FAMILY_SHAPE, F32))

@@ -186,20 +186,42 @@ def test_fo_matches_numpy_oracle():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("block", [512, 2048])
-def test_glcm_ref_vs_pallas_exact(block):
-    imgs, msks = _stack(_cases(2))
-    ref = np.asarray(gk.glcm_matrix_batch_ref(imgs, msks))
-    pal = np.asarray(gk.glcm_matrix_batch_pallas(imgs, msks, block=block,
-                                                 interpret=True))
+def _constant_cube(n=48):
+    """One constant-intensity n^3 ROI filling its volume: every pair lands
+    in cell (0, 0), 3 * (n - 1) * n^2 of them (324,864 at n = 48, past
+    2**16 and spread over many blocks)."""
+    return (np.full((1, n, n, n), 100.0, np.float32),
+            np.ones((1, n, n, n), np.float32))
+
+
+@pytest.mark.parametrize("block,n_bins,case", [
+    pytest.param(512, N_BINS, "random", id="512"),
+    pytest.param(2048, N_BINS, "random", id="2048"),
+    pytest.param(512, 16, "random", id="512-bins16"),
+    pytest.param(2048, 16, "random", id="2048-bins16"),
+    pytest.param(512, 64, "random", id="512-bins64"),
+    pytest.param(2048, 64, "random", id="2048-bins64"),
+    pytest.param(2048, N_BINS, "constant48", id="2048-constant48"),
+])
+def test_glcm_ref_vs_pallas_exact(block, n_bins, case):
+    imgs, msks = _stack(_cases(2)) if case == "random" else _constant_cube()
+    ref = np.asarray(gk.glcm_matrix_batch_ref(imgs, msks, n_bins=n_bins))
+    pal = np.asarray(gk.glcm_matrix_batch_pallas(
+        imgs, msks, n_bins=n_bins, block=block, interpret=True))
     np.testing.assert_array_equal(ref, pal)
+    for i in range(len(imgs)):
+        np.testing.assert_array_equal(
+            pal[i], np_glcm_matrix(imgs[i], msks[i], n_bins))
     # integer-valued counts, symmetric
     np.testing.assert_array_equal(ref, np.round(ref))
     np.testing.assert_array_equal(ref, np.transpose(ref, (0, 2, 1)))
     np.testing.assert_array_equal(
-        gk.glcm_features_from_matrix_np(ref),
-        gk.glcm_features_from_matrix_np(pal),
+        gk.glcm_features_from_matrix_np(ref, n_bins),
+        gk.glcm_features_from_matrix_np(pal, n_bins),
     )
+    if case == "constant48":
+        assert pal[0, 0, 0] == 2 * 3 * 47 * 48 * 48  # symmetrised
+        assert pal.sum() == pal[0, 0, 0]
 
 
 def test_glcm_matches_numpy_scatter():
